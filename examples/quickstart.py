@@ -3,7 +3,8 @@
 Runs the same range predicate (x0 < f < x1 over 100K records) through:
   1. a PudSession over the functional PuD machine model (Unmodified
      DRAM, traced + bus-scheduled commands),
-  2. the TPU Pallas kernel path (interpret mode on CPU),
+  2. the Pallas kernel path (compiled on a TPU; interpreted when
+     JAX's backend is the CPU, e.g. with JAX_PLATFORMS=cpu),
   3. the analytical DRAM cost model (throughput/energy projection),
 and checks them against NumPy.
 
@@ -44,8 +45,8 @@ def main() -> None:
     job = session.query(table, Q1(fi=0, x0=x0, x1=x1))
     bitmap_machine = job.result
 
-    # 2. TPU kernel path (Pallas, interpret mode on CPU): one predicate
-    #    of the pair, checked element-wise.
+    # 2. Pallas kernel path (Mosaic on a TPU, interpreted on the CPU
+    #    backend): one predicate of the pair, checked element-wise.
     bitmap_kernel = np.asarray(ops.clutch_compare(
         jnp.asarray(values.astype(np.uint32)), x0,
         make_plan(n_bits, 5)))

@@ -145,9 +145,14 @@ def reference_leaf_addrs(forest: ObliviousForest, X: np.ndarray
 
 
 def reference_predict(forest: ObliviousForest, X: np.ndarray) -> np.ndarray:
+    """[B] float32 ground-truth predictions.  Each instance's leaf values
+    are summed over the trees as one contiguous float32 row, the order
+    :func:`assemble_leaves` specifies: two float32 orders over a
+    1000-tree forest of unit-scale leaves differ by ~1e-4, far above
+    one ulp, so the order is part of the expected answer."""
     addrs = reference_leaf_addrs(forest, X)
-    return np.take_along_axis(forest.leaves, addrs.T, axis=1).sum(0
-        ).astype(np.float32)
+    per_tree = np.take_along_axis(forest.leaves, addrs.T, axis=1)  # [T, B]
+    return np.ascontiguousarray(per_tree.T).sum(-1).astype(np.float32)
 
 
 class GbdtPudEngine:

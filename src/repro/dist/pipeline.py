@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -63,6 +62,6 @@ def pipeline_forward(stage_fn, mesh, axis: str, stage_params, xs):
         # only the last stage holds real outputs; broadcast them
         return jax.lax.psum(jnp.where(idx == last, outs, 0), axis)
 
-    fn = shard_map(run, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=(P(axis), P()),
+                       out_specs=P(), check_vma=False)
     return fn(stage_params, xs)

@@ -24,9 +24,11 @@ path's exact NumPy expressions).  Fused executables are compile-cached
 per (plan, table shape, query kind); scalars/features are traced
 operands, so repeated jobs re-trace zero times.
 
-On-hardware note: the small host-resolved index vectors are passed as
-plain VMEM operands for interpret-mode portability; on real TPUs they
-would ride PrefetchScalarGridSpec (SMEM) -- a mechanical swap.
+Memory placement: the host-resolved row indices sit in SMEM, where a
+scalar read may feed a dynamic sublane offset; LUT tiles sit in VMEM.
+On a TPU the kernels compile to Mosaic; on the CPU backend (the tests)
+they run in Pallas interpret mode; any other backend is refused
+(:func:`~repro.kernels.common.use_interpret`).
 """
 
 from . import ops, ref  # noqa: F401

@@ -21,7 +21,7 @@ def _kernel(nota_ref, planes_ref, out_ref, *, n_bits: int):
     borrow = jnp.zeros_like(out_ref[...])
     for i in range(n_bits):
         not_a = nota_ref[i]                       # 0x0 or 0xFFFFFFFF
-        plane = pl.load(planes_ref, (pl.ds(i, 1), slice(None)))[0]
+        plane = planes_ref[i]
         borrow = maj3(jnp.broadcast_to(not_a, borrow.shape), plane, borrow)
     out_ref[...] = borrow
 

@@ -1,14 +1,15 @@
 """Pallas TPU kernel: Clutch chunk-merge (Algorithm 1) over packed planes.
 
-One grid step processes a ``(R, BW)`` VMEM tile of the stacked LUT: it
-gathers the ``lt``/``le`` planes for every chunk with dynamic sublane
-slices (the TPU analogue of row activation) and folds them with the
+One grid program per *(bank shard, word block)* processes a ``(R, bw)``
+VMEM tile of that bank's stacked LUT: it loads the ``lt``/``le`` planes
+of every chunk with dynamic one-sublane loads (the TPU analogue of row
+activation), their indices read from SMEM, and folds them with the
 NOT-free MAJ3 recurrence, so per-chunk intermediates never leave VMEM --
 mirroring how Clutch keeps per-chunk bitmaps inside the DRAM subarray.
 
-VMEM budget: R x BW x 4 bytes for the LUT tile (e.g. 448 rows x 1024 words
-= 1.75 MiB) + one BW output line; BW is chosen by ops.py to keep the
-working set < 4 MiB.
+VMEM budget: ``bw`` comes from :func:`~repro.kernels.common.vmem_block`,
+which keeps the R x bw x 4-byte LUT tile within 4 MiB (e.g. 448 rows x
+2048 words = 3.5 MiB); the output is one ``(1, 1, bw)`` word row.
 """
 
 from __future__ import annotations
@@ -18,57 +19,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import SUBLANES, maj3, use_interpret
-
-
-def _kernel(lt_idx_ref, le_idx_ref, lut_ref, out_ref, *, num_chunks: int):
-    def row(idx):
-        # dynamic one-sublane gather from the VMEM-resident LUT tile
-        return pl.load(lut_ref, (pl.ds(idx, 1), slice(None)))[0]
-
-    acc = row(lt_idx_ref[0])
-    for j in range(1, num_chunks):
-        acc = maj3(acc, row(lt_idx_ref[j]), row(le_idx_ref[j]))
-    out_ref[...] = acc
+from .common import LANES, SUBLANES, clutch_fold, use_interpret, vmem_block
 
 
-def clutch_merge(lut: jnp.ndarray, lt_idx: jnp.ndarray, le_idx: jnp.ndarray,
-                 block_words: int = 1024) -> jnp.ndarray:
-    """lut: [R, W] uint32 (R % 8 == 0, W % 128 == 0); lt_idx/le_idx: [C]
-    int32.  Returns [W] uint32 bitmap of ``a < B``."""
-    r, w = lut.shape
-    assert r % SUBLANES == 0 and w % 128 == 0, (r, w)
-    c = lt_idx.shape[0]
-    from .common import choose_block
-    bw = choose_block(w, min(block_words, w))
-    grid = (w // bw,)
-    kernel = functools.partial(_kernel, num_chunks=c)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((r, bw), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((bw,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.uint32),
-        interpret=use_interpret(),
-    )(lt_idx, le_idx, lut)
-
-
-def _banked_kernel(lt_idx_ref, le_idx_ref, lut_ref, out_ref, *,
-                   num_chunks: int):
-    # refs carry a leading singleton bank axis selected by the grid
-    def row(idx):
-        return pl.load(lut_ref,
-                       (pl.ds(0, 1), pl.ds(idx, 1), slice(None)))[0, 0]
-
-    acc = row(lt_idx_ref[0, 0])
-    for j in range(1, num_chunks):
-        acc = maj3(acc, row(lt_idx_ref[0, j]), row(le_idx_ref[0, j]))
-    out_ref[0, ...] = acc
+def _banked_kernel(idx_ref, lut_ref, out_ref, *, num_chunks: int):
+    # idx_ref: the whole [B, 2C] (lt | le) index array in SMEM; the LUT
+    # and output refs carry a leading singleton bank axis
+    bi = pl.program_id(0)
+    c = num_chunks
+    out_ref[0] = clutch_fold(lambda i: lut_ref[0, pl.ds(i, 1), :],
+                             lambda j: idx_ref[bi, j],
+                             lambda j: idx_ref[bi, c + j], c)
 
 
 def clutch_merge_banked(lut: jnp.ndarray, lt_idx: jnp.ndarray,
@@ -83,22 +46,29 @@ def clutch_merge_banked(lut: jnp.ndarray, lt_idx: jnp.ndarray,
     its own scalar).  Returns [B, W] uint32 bitmaps of ``a_b < B_b``.
     """
     b, r, w = lut.shape
-    assert r % SUBLANES == 0 and w % 128 == 0, (r, w)
+    assert r % SUBLANES == 0 and w % LANES == 0, (r, w)
     assert lt_idx.shape == le_idx.shape == (b, lt_idx.shape[1])
     c = lt_idx.shape[1]
-    from .common import choose_block
-    bw = choose_block(w, min(block_words, w))
-    grid = (b, w // bw)
-    kernel = functools.partial(_banked_kernel, num_chunks=c)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    bw = vmem_block(r, w, block_words)
+    idx = jnp.concatenate([lt_idx, le_idx], axis=1).astype(jnp.int32)
+    out = pl.pallas_call(
+        functools.partial(_banked_kernel, num_chunks=c),
+        grid=(b, w // bw),
         in_specs=[
-            pl.BlockSpec((1, c), lambda bi, i: (bi, 0)),
-            pl.BlockSpec((1, c), lambda bi, i: (bi, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, r, bw), lambda bi, i: (bi, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, bw), lambda bi, i: (bi, i)),
-        out_shape=jax.ShapeDtypeStruct((b, w), jnp.uint32),
+        out_specs=pl.BlockSpec((1, 1, bw), lambda bi, i: (bi, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, w), jnp.uint32),
         interpret=use_interpret(),
-    )(lt_idx, le_idx, lut)
+    )(idx, lut)
+    return out.reshape(b, w)
+
+
+def clutch_merge(lut: jnp.ndarray, lt_idx: jnp.ndarray, le_idx: jnp.ndarray,
+                 block_words: int = 1024) -> jnp.ndarray:
+    """lut: [R, W] uint32 (R % 8 == 0, W % 128 == 0); lt_idx/le_idx: [C]
+    int32.  Returns [W] uint32 bitmap of ``a < B`` (the one-bank case
+    of :func:`clutch_merge_banked`)."""
+    return clutch_merge_banked(lut[None], lt_idx[None], le_idx[None],
+                               block_words)[0]

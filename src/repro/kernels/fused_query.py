@@ -1,24 +1,18 @@
 """Pallas TPU kernels: fused predicates + popcount (beyond-paper).
 
-``fused_range_count`` evaluates ``x0 < B < x1`` in a single VMEM pass:
-the ``>``-side merge runs on the normal LUT, the ``<``-side on the
-complement LUT (the NOT-free rewrite Unmodified PuD uses), the two
-bitmaps are ANDed and popcounted -- fusing what the paper executes as
-separate PuD predicate + reduction + host COUNT steps.
-
-``fused_predicate_banked`` generalizes that fusion to a WHOLE resource:
-one ``pallas_call`` grid over *(shard, word block)* evaluates one or
-two range predicates (AND/OR combined) against a stacked LUT holding
-every feature's normal+complement planes for every record shard, and
-accumulates a per-shard popcount -- the entire device half of a Q1-Q5
-query in ONE kernel launch, no per-group Python loop.  It is the
-batched engine behind :mod:`repro.kernels.fused_session`.
-
-``fused_compound_banked`` extends that to compound predicates
-(``Q1 AND Q2 OR Q3``): per-term bitmaps (each term's ranges combined
-with its internal AND/OR) folded through the connective chain in
-registers, one launch per compound -- the fused mirror of the machine
-path's in-bank Ambit AND/OR merge, bit-exact against it.
+``fused_compound_banked`` is the fused backend's one predicate kernel:
+one ``pallas_call`` grid over *(shard, word block)* evaluates a whole
+WHERE clause against a stacked LUT holding every feature's
+normal+complement planes for every record shard.  Each range ``x0 < B
+< x1`` runs its ``>``-side merge on the normal planes and its ``<``-side
+on the complement planes (the NOT-free rewrite Unmodified PuD uses);
+ranges combine with their term's AND/OR, terms fold through the
+connective chain (``Q1 AND Q2 OR Q3``) in registers, and a per-shard
+popcount accumulates across the word blocks -- the entire device half
+of a Q1-Q5 or compound query in ONE launch, the register-level mirror
+of the machine path's in-bank Ambit AND/OR merge, bit-exact against
+it.  ``fused_predicate_banked`` (one term) and ``fused_range_count``
+(one range over separate normal/complement LUTs) are its special cases.
 
 The merge loop never reads ``le[0]`` and ``maj3(acc, zero_row,
 one_row) == acc``, so callers with heterogeneous per-column chunk
@@ -28,11 +22,20 @@ counts (:class:`repro.kernels.fused_session.FusedTableExec` with
 the kernels themselves are chunk-count-uniform and unchanged.
 
 ``gbdt_leafbits_banked`` is the GBDT counterpart: one grid over
-*(instance, word block)* folds every feature's per-instance threshold
-comparison (per-instance gather indices, like the banked machine's
-broadcast wave with per-bank lookups) through the one-hot feature
-masks into the leaf-address bitmap row -- the whole per-wave compute
-loop of :class:`repro.apps.gbdt.GbdtPudEngine` as one kernel.
+*(8-instance block, word block)* folds every feature's per-instance
+threshold comparison (per-instance gather indices, like the banked
+machine's broadcast wave with per-bank lookups) through the one-hot
+feature masks into the leaf-address bitmap rows -- the whole per-wave
+compute loop of :class:`repro.apps.gbdt.GbdtPudEngine` as one kernel.
+
+Memory placement: the LUT tile ``(rows, bw)`` sits in VMEM with ``bw``
+from :func:`~repro.kernels.common.vmem_block`; the row indices sit in
+SMEM, where a scalar read that feeds a dynamic sublane offset is legal.
+A predicate's indices are one small vector (whole array in SMEM); a
+GBDT batch's are read an ``(8, F*2*C)`` block per grid step, so any
+batch size fits SMEM's 1 MiB.  A shard's bitmap row leaves as a
+``(1, 1, bw)`` block of an ``[S, 1, W]`` array, its popcount as an
+int32 ``(1, 1, 128)`` tile.
 """
 
 from __future__ import annotations
@@ -42,189 +45,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import SUBLANES, maj3, use_interpret
+from .common import (
+    LANES,
+    SUBLANES,
+    clutch_fold,
+    round_up,
+    use_interpret,
+    vmem_block,
+)
 
-
-def _merge(lut_ref, lt_idx, le_idx, num_chunks):
-    def row(idx):
-        return pl.load(lut_ref, (pl.ds(idx, 1), slice(None)))[0]
-
-    acc = row(lt_idx[0])
-    for j in range(1, num_chunks):
-        acc = maj3(acc, row(lt_idx[j]), row(le_idx[j]))
-    return acc
-
-
-def _kernel(idx_ref, lut_ref, lutc_ref, bm_ref, cnt_ref, *, num_chunks: int):
-    c = num_chunks
-    gt = _merge(lut_ref, idx_ref[0:c], idx_ref[c:2 * c], c)
-    lt = _merge(lutc_ref, idx_ref[2 * c:3 * c], idx_ref[3 * c:4 * c], c)
-    bm = gt & lt
-    bm_ref[...] = bm
-    block_count = jax.lax.population_count(bm).astype(jnp.uint32).sum()
-    # accumulate across grid steps (TPU grid is sequential per core)
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        cnt_ref[0] = jnp.uint32(0)
-    cnt_ref[0] += block_count
-
-
-def fused_range_count(lut: jnp.ndarray, lut_c: jnp.ndarray,
-                      idx: jnp.ndarray, num_chunks: int,
-                      block_words: int = 1024
-                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """lut/lut_c: [R, W] uint32 stacked (normal / complement) planes;
-    idx: [4*C] int32 = concat(gt_lt, gt_le, lt_lt, lt_le) row indices.
-    Returns (bitmap [W] uint32, count [1] uint32)."""
-    r, w = lut.shape
-    assert lut_c.shape == lut.shape
-    assert r % SUBLANES == 0 and w % 128 == 0
-    from .common import choose_block
-    bw = choose_block(w, min(block_words, w))
-    kernel = functools.partial(_kernel, num_chunks=num_chunks)
-    return pl.pallas_call(
-        kernel,
-        grid=(w // bw,),
-        in_specs=[
-            pl.BlockSpec((4 * num_chunks,), lambda i: (0,)),
-            pl.BlockSpec((r, bw), lambda i: (0, i)),
-            pl.BlockSpec((r, bw), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bw,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((w,), jnp.uint32),
-            jax.ShapeDtypeStruct((1,), jnp.uint32),
-        ],
-        interpret=use_interpret(),
-    )(idx, lut, lut_c)
-
-
-# --------------------------------------------------------------------- #
-# Resource-batched fused predicates (the fused-session engine)
-# --------------------------------------------------------------------- #
-
-def _vmem_block(rows: int, w: int, preferred: int,
-                budget_bytes: int = 4 << 20) -> int:
-    """Block width keeping an (rows, bw) uint32 LUT tile under the VMEM
-    budget.  The full width wins whenever the tile fits -- W is often
-    128 * odd (no power-of-two divisor above the lane count), and
-    falling back to 128-word blocks there would multiply grid steps by
-    W/128 for no locality gain.  Otherwise the largest power-of-two
-    divisor under budget (>= 128 lanes -- tiny tiles always fit)."""
-    if rows * w * 4 <= budget_bytes:
-        return w
-    from .common import choose_block
-    bw = choose_block(w, min(preferred, w))
-    while bw > 128 and rows * bw * 4 > budget_bytes:
-        bw //= 2
-    assert w % bw == 0, (w, bw)
-    return bw
-
-
-def _predicate_kernel(idx_ref, lut_ref, bm_ref, cnt_ref, *,
-                      num_chunks: int, num_ranges: int, disjunction: bool):
-    c = num_chunks
-
-    def row(i):
-        # dynamic one-sublane gather from the shard's VMEM-resident tile
-        return pl.load(lut_ref, (pl.ds(0, 1), pl.ds(i, 1), slice(None))
-                       )[0, 0]
-
-    def merge(off):
-        # Algorithm 1 over idx[off:off+C] (lt) / idx[off+C:off+2C] (le)
-        acc = row(idx_ref[off])
-        for j in range(1, c):
-            acc = maj3(acc, row(idx_ref[off + j]), row(idx_ref[off + c + j]))
-        return acc
-
-    def range_bm(rix):
-        # gt-side on the normal planes, lt-side on the complement planes
-        off = rix * 4 * c
-        return merge(off) & merge(off + 2 * c)
-
-    bm = range_bm(0)
-    for rix in range(1, num_ranges):
-        nxt = range_bm(rix)
-        bm = (bm | nxt) if disjunction else (bm & nxt)
-    bm_ref[0, ...] = bm
-    # per-shard popcount accumulated across the word-block grid axis
-    # (TPU grids are sequential per core; interpret mode likewise)
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        cnt_ref[0] = jnp.uint32(0)
-    cnt_ref[0] += jax.lax.population_count(bm).astype(jnp.uint32).sum()
-
-
-def fused_predicate_banked(lut: jnp.ndarray, idx: jnp.ndarray,
-                           num_chunks: int, num_ranges: int,
-                           disjunction: bool = False,
-                           block_words: int = 1024
-                           ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One-launch Q1-Q3-shaped predicate over a whole sharded resource.
-
-    lut: [S, R, W] uint32 -- per record shard, every feature's stacked
-    normal planes followed by every feature's complement planes (row
-    offsets are the caller's business; see
-    :class:`repro.kernels.fused_session.FusedTableExec`).
-    idx: [num_ranges * 4 * C] int32 -- per range predicate, the
-    concatenation (gt_lt, gt_le, lt_lt, lt_le) of Algorithm 1 row
-    indices, already offset to the right feature block.  ``num_ranges``
-    is 1 (plain range) or 2 combined with AND (``disjunction=False``)
-    or OR.  Returns (bitmap [S, W] uint32, per-shard popcount [S]
-    uint32) -- bitmap AND/OR *and* COUNT leave the kernel in one pass.
-    """
-    s, r, w = lut.shape
-    assert r % SUBLANES == 0 and w % 128 == 0, (r, w)
-    assert idx.shape == (num_ranges * 4 * num_chunks,), idx.shape
-    bw = _vmem_block(r, w, block_words)
-    kernel = functools.partial(_predicate_kernel, num_chunks=num_chunks,
-                               num_ranges=num_ranges,
-                               disjunction=disjunction)
-    return pl.pallas_call(
-        kernel,
-        grid=(s, w // bw),
-        in_specs=[
-            pl.BlockSpec((num_ranges * 4 * num_chunks,),
-                         lambda si, i: (0,)),
-            pl.BlockSpec((1, r, bw), lambda si, i: (si, 0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bw), lambda si, i: (si, i)),
-            pl.BlockSpec((1,), lambda si, i: (si,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((s, w), jnp.uint32),
-            jax.ShapeDtypeStruct((s,), jnp.uint32),
-        ],
-        interpret=use_interpret(),
-    )(idx, lut)
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _compound_kernel(idx_ref, lut_ref, bm_ref, cnt_ref, *,
                      num_chunks: int, term_ranges: tuple,
                      term_disj: tuple, conn_disj: tuple):
-    """Compound-predicate generalization of :func:`_predicate_kernel`:
-    evaluate each TERM's bitmap first (its own ranges combined with its
+    """Evaluate each TERM's bitmap (its own ranges combined with its
     own internal AND/OR), then fold the term bitmaps left-associatively
-    through the connectives -- the register-level mirror of the machine
-    path's in-bank Ambit AND/OR merge of parked term rows."""
+    through the connectives."""
     c = num_chunks
 
     def row(i):
-        return pl.load(lut_ref, (pl.ds(0, 1), pl.ds(i, 1), slice(None))
-                       )[0, 0]
+        # dynamic one-sublane load from the shard's VMEM-resident tile
+        return lut_ref[0, pl.ds(i, 1), :]
 
     def merge(off):
-        acc = row(idx_ref[off])
-        for j in range(1, c):
-            acc = maj3(acc, row(idx_ref[off + j]), row(idx_ref[off + c + j]))
-        return acc
+        # Algorithm 1 over idx[off:off+C] (lt) / idx[off+C:off+2C] (le)
+        return clutch_fold(row, lambda j: idx_ref[off + j],
+                           lambda j: idx_ref[off + c + j], c)
 
     def range_bm(rix):
+        # gt-side on the normal planes, lt-side on the complement planes
         off = rix * 4 * c
         return merge(off) & merge(off + 2 * c)
 
@@ -241,11 +94,16 @@ def _compound_kernel(idx_ref, lut_ref, bm_ref, cnt_ref, *,
             acc = tb
         else:
             acc = (acc | tb) if conn_disj[t - 1] else (acc & tb)
-    bm_ref[0, ...] = acc
+    bm_ref[0] = acc
+
+    # per-shard popcount accumulated across the word-block grid axis
+    # (TPU grids run sequentially per core; interpret mode likewise)
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        cnt_ref[0] = jnp.uint32(0)
-    cnt_ref[0] += jax.lax.population_count(acc).astype(jnp.uint32).sum()
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+    # int32: Mosaic has no unsigned reductions
+    cnt_ref[...] += jax.lax.population_count(acc).astype(jnp.int32).sum()
 
 
 def fused_compound_banked(lut: jnp.ndarray, idx: jnp.ndarray,
@@ -256,46 +114,82 @@ def fused_compound_banked(lut: jnp.ndarray, idx: jnp.ndarray,
     """One-launch compound predicate (``term0 <op0> term1 ...``) over a
     whole sharded resource.
 
-    ``lut``/``idx`` are laid out exactly as in
-    :func:`fused_predicate_banked`, with ``idx`` holding the
-    concatenated 4*C row-index blocks of EVERY range of every term, in
-    term order.  Static structure (the compile-cache key upstream):
-    ``term_ranges[t]`` ranges per term, combined with that term's
-    internal ``term_disj[t]`` (True = OR), then the term bitmaps folded
-    through ``conn_disj`` (one entry per connective, True = OR,
+    lut: [S, R, W] uint32 -- per record shard, every feature's stacked
+    normal planes followed by every feature's complement planes (row
+    offsets are the caller's business; see
+    :class:`repro.kernels.fused_session.FusedTableExec`).
+    idx: [sum(term_ranges) * 4 * C] int32 -- per range, in term order,
+    the concatenation (gt_lt, gt_le, lt_lt, lt_le) of Algorithm 1 row
+    indices, already offset to the right feature block.  Static
+    structure (the compile-cache key upstream): ``term_ranges[t]``
+    ranges per term, combined with that term's internal
+    ``term_disj[t]`` (True = OR), then the term bitmaps folded through
+    ``conn_disj`` (one entry per connective, True = OR,
     left-associative).  Returns (bitmap [S, W] uint32, per-shard
-    popcount [S] uint32) -- the whole WHERE clause and its COUNT leave
+    popcount [S] int32) -- the whole WHERE clause and its COUNT leave
     the kernel in one pass, matching the machine path's in-DRAM merge
     contract of one-readout-per-compound."""
     s, r, w = lut.shape
     total_ranges = sum(term_ranges)
     assert len(term_disj) == len(term_ranges)
     assert len(conn_disj) == len(term_ranges) - 1
-    assert r % SUBLANES == 0 and w % 128 == 0, (r, w)
+    assert r % SUBLANES == 0 and w % LANES == 0, (r, w)
     assert idx.shape == (total_ranges * 4 * num_chunks,), idx.shape
-    bw = _vmem_block(r, w, block_words)
+    bw = vmem_block(r, w, block_words)
     kernel = functools.partial(_compound_kernel, num_chunks=num_chunks,
                                term_ranges=tuple(term_ranges),
                                term_disj=tuple(term_disj),
                                conn_disj=tuple(conn_disj))
-    return pl.pallas_call(
+    bm, cnt = pl.pallas_call(
         kernel,
         grid=(s, w // bw),
         in_specs=[
-            pl.BlockSpec((total_ranges * 4 * num_chunks,),
-                         lambda si, i: (0,)),
+            _SMEM,
             pl.BlockSpec((1, r, bw), lambda si, i: (si, 0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bw), lambda si, i: (si, i)),
-            pl.BlockSpec((1,), lambda si, i: (si,)),
+            pl.BlockSpec((1, 1, bw), lambda si, i: (si, 0, i)),
+            pl.BlockSpec((1, 1, LANES), lambda si, i: (si, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((s, w), jnp.uint32),
-            jax.ShapeDtypeStruct((s,), jnp.uint32),
+            jax.ShapeDtypeStruct((s, 1, w), jnp.uint32),
+            jax.ShapeDtypeStruct((s, 1, LANES), jnp.int32),
         ],
         interpret=use_interpret(),
-    )(idx, lut)
+    )(idx.astype(jnp.int32), lut)
+    return bm.reshape(s, w), cnt[:, 0, 0]
+
+
+def fused_predicate_banked(lut: jnp.ndarray, idx: jnp.ndarray,
+                           num_chunks: int, num_ranges: int,
+                           disjunction: bool = False,
+                           block_words: int = 1024
+                           ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One-launch Q1-Q3-shaped predicate over a whole sharded resource:
+    :func:`fused_compound_banked` with one term of ``num_ranges`` (1 or
+    2) ranges combined with AND (``disjunction=False``) or OR.  Returns
+    (bitmap [S, W] uint32, per-shard popcount [S] int32)."""
+    return fused_compound_banked(lut, idx, num_chunks, (num_ranges,),
+                                 (disjunction,), (), block_words)
+
+
+def fused_range_count(lut: jnp.ndarray, lut_c: jnp.ndarray,
+                      idx: jnp.ndarray, num_chunks: int,
+                      block_words: int = 1024
+                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``x0 < B < x1`` bitmap + COUNT in one pass.  lut/lut_c: [R, W]
+    uint32 normal / complement planes; idx: [4*C] int32 =
+    concat(gt_lt, gt_le, lt_lt, lt_le) row indices, the lt-side ones
+    into ``lut_c``.  Returns (bitmap [W] uint32, count [1] int32)."""
+    r, _ = lut.shape
+    assert lut_c.shape == lut.shape
+    c = num_chunks
+    # one stacked shard: the complement planes follow the normal ones
+    stacked = jnp.concatenate([lut, lut_c])[None]
+    idx = idx.astype(jnp.int32).at[2 * c:].add(r)
+    bm, cnt = fused_predicate_banked(stacked, idx, c, 1,
+                                     block_words=block_words)
+    return bm[0], cnt
 
 
 def _leafbits_kernel(idx_ref, lut_ref, mask_ref, bm_ref, *,
@@ -303,21 +197,17 @@ def _leafbits_kernel(idx_ref, lut_ref, mask_ref, bm_ref, *,
     c = num_chunks
 
     def row(i):
-        return pl.load(lut_ref, (pl.ds(i, 1), slice(None)))[0]
+        return lut_ref[pl.ds(i, 1), :]
 
-    def merge(off):
-        acc = row(idx_ref[0, off])
-        for j in range(1, c):
-            acc = maj3(acc, row(idx_ref[0, off + j]),
-                       row(idx_ref[0, off + c + j]))
-        return acc
-
-    acc = jnp.zeros_like(mask_ref[0])
-    for f in range(num_features):
-        # cmp = Clutch(v_f < thresholds); acc |= cmp AND mask_f
-        cmp = merge(f * 2 * c)
-        acc = acc | (cmp & mask_ref[f])
-    bm_ref[0, ...] = acc
+    for b in range(SUBLANES):
+        acc = jnp.zeros((1, bm_ref.shape[1]), jnp.uint32)
+        for f in range(num_features):
+            # cmp = Clutch(v_f < thresholds); acc |= cmp AND mask_f
+            off = f * 2 * c
+            cmp = clutch_fold(row, lambda j: idx_ref[b, off + j],
+                              lambda j: idx_ref[b, off + c + j], c)
+            acc = acc | (cmp & mask_ref[f:f + 1, :])
+        bm_ref[b:b + 1, :] = acc
 
 
 def gbdt_leafbits_banked(lut: jnp.ndarray, masks: jnp.ndarray,
@@ -332,27 +222,32 @@ def gbdt_leafbits_banked(lut: jnp.ndarray, masks: jnp.ndarray,
     ``num_features`` are padding).  idx: [B, F * 2 * C] int32 --
     per instance, per feature, (lt, le) Algorithm 1 row indices for
     that instance's feature value (the per-bank gather of the machine
-    model).  Returns the leaf-address bitmap [B, W] uint32.
+    model).  Returns the leaf-address bitmap [B, W] uint32.  Each grid
+    step takes 8 instances; a batch that is not a multiple of 8 is
+    padded here and the padding rows dropped.
     """
     r, w = lut.shape
     fp, wm = masks.shape
-    b = idx.shape[0]
-    assert wm == w and r % SUBLANES == 0 and w % 128 == 0, (r, w, fp)
+    b, k = idx.shape
+    assert wm == w and r % SUBLANES == 0 and w % LANES == 0, (r, w, fp)
     assert fp % SUBLANES == 0 and fp >= num_features
-    assert idx.shape == (b, num_features * 2 * num_chunks), idx.shape
-    bw = _vmem_block(r + fp, w, block_words)
+    assert k == num_features * 2 * num_chunks, idx.shape
+    b_pad = round_up(max(b, 1), SUBLANES)
+    idx = jnp.pad(idx.astype(jnp.int32), ((0, b_pad - b), (0, 0)))
+    bw = vmem_block(r + fp, w, block_words)
     kernel = functools.partial(_leafbits_kernel, num_chunks=num_chunks,
                                num_features=num_features)
-    return pl.pallas_call(
+    bm = pl.pallas_call(
         kernel,
-        grid=(b, w // bw),
+        grid=(b_pad // SUBLANES, w // bw),
         in_specs=[
-            pl.BlockSpec((1, num_features * 2 * num_chunks),
-                         lambda bi, i: (bi, 0)),
+            pl.BlockSpec((SUBLANES, k), lambda bi, i: (bi, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((r, bw), lambda bi, i: (0, i)),
             pl.BlockSpec((fp, bw), lambda bi, i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((1, bw), lambda bi, i: (bi, i)),
-        out_shape=jax.ShapeDtypeStruct((b, w), jnp.uint32),
+        out_specs=pl.BlockSpec((SUBLANES, bw), lambda bi, i: (bi, i)),
+        out_shape=jax.ShapeDtypeStruct((b_pad, w), jnp.uint32),
         interpret=use_interpret(),
     )(idx, lut, masks)
+    return bm[:b]

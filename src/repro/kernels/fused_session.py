@@ -70,8 +70,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.encoding import ChunkPlan, ColumnPlan, make_plan
 from repro.core.machine import pack_bits, unpack_bits
@@ -157,7 +156,9 @@ class FusedTableExec:
                         off += int(blk.shape[0])
                     cols.append(blk)
             shards.append(jnp.concatenate(cols, axis=0))
-        self.lut = jnp.stack(shards)            # [S, sum(blocks), W]
+        # [S, sum(blocks), W], each device holding its own shards
+        self.lut = jax.device_put(
+            jnp.stack(shards), NamedSharding(self.mesh, P("shards")))
         self._base_n = base[:self.num_features]
         self._base_c = base[self.num_features:]
         self.r_pad = int(shards[0].shape[0]) // (2 * self.num_features)
@@ -183,15 +184,14 @@ class FusedTableExec:
                 self.trace_counts[key] = self.trace_counts.get(key, 0) + 1
                 bm, cnt = fused_predicate_banked(
                     lut, idx, c, num_ranges, disjunction)
-                total = jax.lax.psum(cnt.astype(jnp.uint32).sum(), axis)
-                return bm, total
+                return bm, jax.lax.psum(cnt.sum(), axis)
 
-            # check_rep=False: pallas_call has no replication rule; the
+            # check_vma=False: pallas_call has no replication rule; the
             # psum output is genuinely replicated regardless.
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(axis), P()), out_specs=(P(axis), P()),
-                check_rep=False))
+                check_vma=False))
             self._fns[key] = fn
         return fn
 
@@ -210,13 +210,12 @@ class FusedTableExec:
                 self.trace_counts[key] = self.trace_counts.get(key, 0) + 1
                 bm, cnt = fused_compound_banked(
                     lut, idx, c, term_ranges, term_disj, conn_disj)
-                total = jax.lax.psum(cnt.astype(jnp.uint32).sum(), axis)
-                return bm, total
+                return bm, jax.lax.psum(cnt.sum(), axis)
 
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(axis), P()), out_specs=(P(axis), P()),
-                check_rep=False))
+                check_vma=False))
             self._fns[key] = fn
         return fn
 
@@ -381,9 +380,12 @@ class FusedGbdtExec:
         f_pad, w = round_up(f, SUBLANES), int(self.lut.shape[1])
         masks = np.zeros((f_pad, w), np.uint32)
         masks[:f, :words.shape[1]] = words
-        self.masks = jnp.asarray(masks)
         self.mesh = mesh if mesh is not None else shard_mesh(
             max(jax.device_count(), 1))
+        # the LUT and masks are replicated on every device of the mesh
+        rep = NamedSharding(self.mesh, P())
+        self.lut = jax.device_put(self.lut, rep)
+        self.masks = jax.device_put(jnp.asarray(masks), rep)
         self.trace_counts: dict[tuple, int] = {}
         self._fn_cached = None
 
@@ -397,10 +399,10 @@ class FusedGbdtExec:
                 return gbdt_leafbits_banked(lut, masks, idx, c, f)
 
             axis = "shards"
-            self._fn_cached = jax.jit(shard_map(
+            self._fn_cached = jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(), P(), P(axis)), out_specs=P(axis),
-                check_rep=False))
+                check_vma=False))
         return self._fn_cached
 
     def leaf_addrs(self, X: np.ndarray) -> np.ndarray:
@@ -411,8 +413,9 @@ class FusedGbdtExec:
         if self._clamp:
             X = np.minimum(X.astype(np.int64), self.mx)
         b = X.shape[0]
+        # whole 8-instance kernel blocks on every device
         d = self.mesh.shape["shards"]
-        b_pad = round_up(max(b, 1), d)
+        b_pad = round_up(max(b, 1), SUBLANES * d)
         if b_pad != b:
             X = np.concatenate([X, np.repeat(X[:1], b_pad - b, axis=0)])
         cols = []

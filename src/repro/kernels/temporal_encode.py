@@ -9,7 +9,7 @@ Layout trick: the 32 values packed into an output word must sit along the
 *lane* dimension for the VPU, so ops.py reshapes values to [W, 32] and the
 kernel reduces the 32-wide trailing dim with shift-or after the compare:
     word[r, w] = sum_i (r < v[w, i]) << i
-computed as a dot with the per-bit weights (1<<i) in uint32 arithmetic.
+summed in int32 and bitcast to the uint32 word.
 """
 
 from __future__ import annotations
@@ -24,12 +24,16 @@ from .common import WORD_BITS, use_interpret
 
 
 def _kernel(vals_ref, out_ref, *, block_rows: int):
+    # int32 throughout (chunk values sit far below 2^31; Mosaic has no
+    # unsigned reductions); the shift-sum of distinct bits wraps into
+    # bit 31 exactly, and the bitcast hands back the uint32 word
     r0 = pl.program_id(0) * block_rows
-    vals = vals_ref[...]                                   # [BW, 32] uint32
-    rows = (r0 + jax.lax.broadcasted_iota(jnp.uint32, (block_rows, 1, 1), 0))
-    bits = (rows < vals[None]).astype(jnp.uint32)          # [BR, BW, 32]
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, WORD_BITS), 2)
-    out_ref[...] = (bits << shifts).sum(axis=-1).astype(jnp.uint32)
+    vals = vals_ref[...].astype(jnp.int32)                 # [BW, 32]
+    rows = (r0 + jax.lax.broadcasted_iota(jnp.int32, (block_rows, 1, 1), 0))
+    bits = (rows < vals[None]).astype(jnp.int32)           # [BR, BW, 32]
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, WORD_BITS), 2)
+    out_ref[...] = jax.lax.bitcast_convert_type(
+        (bits << shifts).sum(axis=-1), jnp.uint32)
 
 
 def temporal_encode(vals: jnp.ndarray, k: int, block_rows: int = 8,

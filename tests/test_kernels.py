@@ -8,7 +8,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.encoding import make_plan
-from repro.kernels import ops, ref
+from repro.kernels import common, ops, ref
 from repro.kernels.common import (
     float_to_monotonic_u32,
     unpack_bits_jnp,
@@ -281,6 +281,64 @@ def test_machine_and_kernel_agree():
     kernel_bm = np.asarray(ops.clutch_compare(
         jnp.asarray(vals_np.astype(np.uint32)), a, plan))
     np.testing.assert_array_equal(machine_bm, kernel_bm)
+
+
+@pytest.fixture
+def backend_is(monkeypatch):
+    """Make ``jax.default_backend()`` report a chosen platform while
+    :func:`use_interpret` decides, with its memo cleared around it."""
+    def set_backend(name):
+        monkeypatch.setattr(jax, "default_backend", lambda: name)
+        common.use_interpret.cache_clear()
+
+    yield set_backend
+    common.use_interpret.cache_clear()
+
+
+def test_use_interpret_on_cpu_and_tpu(backend_is):
+    backend_is("cpu")
+    assert common.use_interpret() is True
+    backend_is("tpu")
+    assert common.use_interpret() is False
+
+
+@pytest.mark.parametrize("backend", ["gpu", "rocm", "METAL"])
+def test_use_interpret_refuses_other_backends(backend_is, backend):
+    # a chip that failed to come up must not pass for a run on it
+    backend_is(backend)
+    with pytest.raises(RuntimeError, match=backend):
+        common.use_interpret()
+
+
+def test_compile_cache_dir_honours_env(monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert common.compile_cache_dir() == str(tmp_path)
+
+
+def test_compile_cache_dir_defaults_to_fixed_checkout_path(monkeypatch):
+    from pathlib import Path
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = common.compile_cache_dir()
+    assert first == common.compile_cache_dir()
+    root = Path(__file__).resolve().parents[1]
+    assert Path(first) == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+
+
+def test_enable_compile_cache_sets_jax_only_without_env(monkeypatch,
+                                                        tmp_path):
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert common.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = common.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 @pytest.mark.parametrize("n", [100_000, 4096 + 128 * 32, 33 * 32])
