@@ -16,7 +16,7 @@ sys.path.insert(0, ROOT)
 
 from benchmarks import adaptive_precision, bank_scaling, channel_scaling, \
     host_lane_scaling, indram_ops, kernel_wallclock, paper_figs, \
-    roofline_report, serving_load, session_scaling
+    serving_load, session_scaling
 
 
 def _paper_figs():
@@ -32,7 +32,6 @@ REGISTRY = {
     "channel_scaling": channel_scaling.run,
     "session_scaling": session_scaling.run,
     "host_lane_scaling": host_lane_scaling.run,
-    "roofline_report": roofline_report.run,
     "indram_ops": indram_ops.run,
     "serving_load": serving_load.run,
     "adaptive_precision": adaptive_precision.run,

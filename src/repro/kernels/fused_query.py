@@ -156,6 +156,7 @@ def fused_compound_banked(lut: jnp.ndarray, idx: jnp.ndarray,
             jax.ShapeDtypeStruct((s, 1, LANES), jnp.int32),
         ],
         interpret=use_interpret(),
+        name="clutch_predicate",
     )(idx.astype(jnp.int32), lut)
     return bm.reshape(s, w), cnt[:, 0, 0]
 
@@ -249,5 +250,6 @@ def gbdt_leafbits_banked(lut: jnp.ndarray, masks: jnp.ndarray,
         out_specs=pl.BlockSpec((SUBLANES, bw), lambda bi, i: (bi, i)),
         out_shape=jax.ShapeDtypeStruct((b_pad, w), jnp.uint32),
         interpret=use_interpret(),
+        name="clutch_leafbits",
     )(idx, lut, masks)
     return bm[:b]

@@ -109,6 +109,16 @@ from .planner import Planner
 from .queries import Q1, Q2, Q3, Q4, Q5, Compound
 
 
+def _session_span():
+    """The profiler span around one fused job, ``clutch.session``
+    (imported here: only the fused backend needs JAX)."""
+    from jax.profiler import TraceAnnotation
+
+    from repro.kernels.fused_session import SPAN_SESSION
+
+    return TraceAnnotation(SPAN_SESSION)
+
+
 @dataclass
 class JobResult:
     """One submitted job's outcome: the merged result, plus the cost
@@ -591,14 +601,16 @@ class PudSession:
         ``wallclock_ns`` instead of scheduler stats."""
         single = isinstance(queries, (Q1, Q2, Q3, Q4, Q5, Compound))
         batch = [queries] if single else list(queries)
-        ex = self._executor(table, "table")
         if (backend or self.backend) == "fused":
-            fx = self._fused_exec(table, ex, "table")
-            t0 = time.perf_counter()
-            results = fx.run([q.to_tuple() for q in batch])
-            wall = (time.perf_counter() - t0) * 1e9
-            return JobResult(result=results[0] if single else results,
-                             wallclock_ns=wall, backend="fused")
+            with _session_span():
+                fx = self._fused_exec(
+                    table, self._executor(table, "table"), "table")
+                t0 = time.perf_counter()
+                results = fx.run([q.to_tuple() for q in batch])
+                wall = (time.perf_counter() - t0) * 1e9
+                return JobResult(result=results[0] if single else results,
+                                 wallclock_ns=wall, backend="fused")
+        ex = self._executor(table, "table")
         results = ex.run([q.to_tuple() for q in batch])
         timeline = ex.schedule(self.sys_cfg)
         self._lint_job(ex, timeline)
@@ -614,14 +626,16 @@ class PudSession:
         backend) or measured ``wallclock_ns`` (fused backend --
         bit-exact predictions, one kernel launch for the whole
         batch)."""
-        ex = self._executor(forest, "forest")
         if (backend or self.backend) == "fused":
-            fx = self._fused_exec(forest, ex, "forest")
-            t0 = time.perf_counter()
-            preds = fx.infer(np.asarray(X))
-            wall = (time.perf_counter() - t0) * 1e9
-            return JobResult(result=preds, wallclock_ns=wall,
-                             backend="fused")
+            with _session_span():
+                fx = self._fused_exec(
+                    forest, self._executor(forest, "forest"), "forest")
+                t0 = time.perf_counter()
+                preds = fx.infer(np.asarray(X))
+                wall = (time.perf_counter() - t0) * 1e9
+                return JobResult(result=preds, wallclock_ns=wall,
+                                 backend="fused")
+        ex = self._executor(forest, "forest")
         preds = ex.infer(np.asarray(X))
         timeline = ex.schedule(self.sys_cfg)
         self._lint_job(ex, timeline)

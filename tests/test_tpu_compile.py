@@ -76,6 +76,7 @@ def test_fused_predicate_banked_compiles(mosaic, num_ranges, disjunction):
             lut, idx, CHUNKS, num_ranges, disjunction),
         lut, _table_idx(num_ranges, mosaic))
     assert "tpu_custom_call" in text
+    assert "%clutch_predicate" in text      # the kernel's name in a trace
 
 
 def test_fused_compound_banked_compiles(mosaic):
@@ -87,6 +88,7 @@ def test_fused_compound_banked_compiles(mosaic):
             (False, True)),
         lut, _table_idx(5, mosaic))
     assert "tpu_custom_call" in text
+    assert "%clutch_predicate" in text      # the kernel's name in a trace
 
 
 def test_gbdt_leafbits_banked_compiles(mosaic):
@@ -97,6 +99,7 @@ def test_gbdt_leafbits_banked_compiles(mosaic):
         mosaic((FEATURES, FOREST_WORDS), jnp.uint32),
         mosaic((BATCH, FEATURES * 2), jnp.int32))
     assert "tpu_custom_call" in text
+    assert "%clutch_leafbits" in text      # the kernel's name in a trace
 
 
 def test_encode_lut_compiles(mosaic):
