@@ -25,7 +25,9 @@ per (plan, table shape, query kind); scalars/features are traced
 operands, so repeated jobs re-trace zero times.
 
 Memory placement: the host-resolved row indices sit in SMEM, where a
-scalar read may feed a dynamic sublane offset; LUT tiles sit in VMEM.
+scalar read may feed a dynamic sublane offset or a DMA's source; LUT
+tiles sit in VMEM, except the predicate kernel's stacked LUT, which
+stays in HBM and is gathered row by row (``fused_query.row_slabs``).
 On a TPU the kernels compile to Mosaic; on the CPU backend (the tests)
 they run in Pallas interpret mode; any other backend is refused
 (:func:`~repro.kernels.common.use_interpret`).

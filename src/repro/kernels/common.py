@@ -31,8 +31,10 @@ LANES = 128
 SUBLANES = 8
 
 
-#: VMEM bytes one LUT tile may take (double-buffered by the pipeline,
-#: so well inside the 16 MiB scoped default of a v5e core).
+#: VMEM bytes for a kernel's LUT blocks: one tile that the pipeline
+#: double-buffers (the GBDT kernel), or both gather buffers (the
+#: predicate kernel) -- well inside the 16 MiB scoped default of a v5e
+#: core either way.
 VMEM_TILE_BYTES = 4 << 20
 
 #: Checkout root: ``<root>/src/repro/kernels/common.py``.

@@ -8,10 +8,13 @@ the machine path's semantics exactly:
 
 * :class:`FusedTableExec` -- Q1-Q5 over a record-sharded table.  Every
   feature's normal AND complement LUT planes for every record shard are
-  stacked into ONE ``[shards, rows, words]`` array at build time; a
-  query then runs as ONE jitted program: a single
+  stacked into ONE ``[shards, rows * Wp / 128, 128]`` array at build
+  time, each shard's rows laid out as contiguous slabs of whole tiles
+  (:func:`repro.kernels.fused_query.row_slabs`, ``Wp`` = words rounded
+  up to 1024); a query then runs as ONE jitted program: a single
   :func:`repro.kernels.fused_query.fused_predicate_banked` grid over
-  *(shard, word block)* evaluates the whole WHERE clause (both range
+  *(shard, word block)* gathers from HBM only the LUT rows the query's
+  index lanes name and evaluates the whole WHERE clause (both range
   sides, AND/OR combination, per-shard popcount) and a ``psum`` over a
   ``shard_map`` mesh (built from :func:`repro.dist.sharding.shard_mesh`)
   joins the shard counts -- the PR-5 merge tree's leaves become the
@@ -42,7 +45,8 @@ row-index *arrays* (host-side, memoized via
 operands, so ONE compiled executable per ``(plan, table shape, query
 kind)`` serves every (feature, scalar) combination.  ``trace_counts``
 exposes the per-kind trace counter the zero-retrace regression test
-asserts on.
+asserts on; ``lut_rows_read`` the rows a kind's launch gathers per
+shard against the rows a shard holds (a Q3 at 4 chunks: 32).
 
 Heterogeneous per-column plans: ``plans`` (one
 :class:`~repro.core.encoding.ColumnPlan` per feature) stacks RAGGED
@@ -94,6 +98,7 @@ from .fused_query import (
     fused_compound_banked,
     fused_predicate_banked,
     gbdt_leafbits_banked,
+    row_slabs,
 )
 from .ops import (
     encode_lut,
@@ -120,7 +125,8 @@ class FusedTableExec:
     bitmap order matches the machine path bit for bit.  Padding columns
     encode ``B = 0``; the gt-side of every range predicate is 0 there
     (scalars are non-negative), the AND kills the complement side, and
-    popcounts need no masking.
+    popcounts need no masking.  The words :func:`row_slabs` pads each
+    row with are zero in every plane, so every range is false there too.
     """
 
     def __init__(self, table, num_shards: int, num_chunks: int,
@@ -158,7 +164,9 @@ class FusedTableExec:
         # Per shard: every feature's normal LUT block, then every
         # feature's complement block.  Blocks are ragged -- each is as
         # tall as its own plan's planes (+2 const rows, tile-padded) --
-        # and `base[(comp, f)]` records where each begins.
+        # and `base[(comp, f)]` records where each begins.  Each shard
+        # is laid out as row slabs before the stack, so the stacked
+        # array is never copied whole.
         shards = []
         base: list[int] = []
         for s in range(num_shards):
@@ -175,19 +183,30 @@ class FusedTableExec:
                         base.append(off)
                         off += int(blk.shape[0])
                     cols.append(blk)
-            shards.append(jnp.concatenate(cols, axis=0))
-        # [S, sum(blocks), W], each device holding its own shards
+            shards.append(row_slabs(jnp.concatenate(cols, axis=0)))
+        #: LUT rows and words of one shard
+        self.rows = sum(int(blk.shape[0]) for blk in cols)
+        self.words = int(cols[0].shape[1])
+        # [S, rows * Wp / 128, 128], each device holding its own shards
         self.lut = jax.device_put(
             jnp.stack(shards), NamedSharding(self.mesh, P("shards")))
         self._base_n = base[:self.num_features]
         self._base_c = base[self.num_features:]
-        self.r_pad = int(shards[0].shape[0]) // (2 * self.num_features)
         #: traces per query kind -- the zero-retrace test's probe.
         self.trace_counts: dict[tuple, int] = {}
+        #: per query kind, (LUT rows a launch gathers per shard, rows a
+        #: shard holds), set when the kind is traced.
+        self.lut_rows_read: dict[tuple, tuple[int, int]] = {}
         self._fns: dict[tuple, object] = {}
         self._idx_cache: dict[tuple, np.ndarray] = {}
 
     # ---------------------------- compiled fns ------------------------- #
+    def _traced(self, key: tuple, idx) -> None:
+        """Trace-time bookkeeping of one query kind: its trace count
+        and the LUT rows its launch gathers against a shard's rows."""
+        self.trace_counts[key] = self.trace_counts.get(key, 0) + 1
+        self.lut_rows_read[key] = (int(idx.shape[0]), self.rows)
+
     def _fn(self, num_ranges: int, disjunction: bool):
         """The compiled executable for one query kind: kernel sweep over
         every shard + ``psum`` root join, under one ``jit``.  Cached per
@@ -201,9 +220,9 @@ class FusedTableExec:
 
             def local(lut, idx):
                 # executes at trace time only -> counts (re)traces
-                self.trace_counts[key] = self.trace_counts.get(key, 0) + 1
+                self._traced(key, idx)
                 bm, cnt = fused_predicate_banked(
-                    lut, idx, c, num_ranges, disjunction)
+                    lut, idx, c, num_ranges, disjunction, words=self.words)
                 return bm, jax.lax.psum(cnt.sum(), axis)
 
             # check_vma=False: pallas_call has no replication rule; the
@@ -227,9 +246,10 @@ class FusedTableExec:
             c, axis = self.num_chunks, "shards"
 
             def local(lut, idx):
-                self.trace_counts[key] = self.trace_counts.get(key, 0) + 1
+                self._traced(key, idx)
                 bm, cnt = fused_compound_banked(
-                    lut, idx, c, term_ranges, term_disj, conn_disj)
+                    lut, idx, c, term_ranges, term_disj, conn_disj,
+                    words=self.words)
                 return bm, jax.lax.psum(cnt.sum(), axis)
 
             fn = jax.jit(jax.shard_map(

@@ -124,6 +124,24 @@ def test_fused_table_exec_empty_selection_and_always_true():
     np.testing.assert_array_equal(bm, P.reference_q1(t, 0, 0, 255))
 
 
+def test_lut_rows_read_counts_the_gathered_rows():
+    """A Q3 at 4 chunks gathers its 2 ranges x 4 x C = 32 index lanes'
+    rows of each shard, whatever the shard holds; a compound of 5
+    ranges gathers 80, more than this small table's rows."""
+    t = P.Table.generate(3000, 16, num_features=3, seed=2)
+    ex = FusedTableExec(t, num_shards=2, num_chunks=4)
+    # 3 features x (normal + complement) x (4 x 15 planes + 2, tiled)
+    assert ex.rows == 3 * 2 * 64
+    got = ex.run([("q3", 0, 100, 40000, 1, 2000, 60000)])[0]
+    assert got == P.reference_q3(t, 0, 100, 40000, 1, 2000, 60000)
+    assert ex.lut_rows_read == {(2, True): (32, ex.rows)}
+    q1, q2 = ("q1", 2, 5, 9000), ("q2", 0, 1, 30000, 1, 7, 65000)
+    ex.run([("compound", True, None, ("or", "and"), (q1, q2, q2))])
+    assert ex.lut_rows_read[
+        ("compound", (1, 2, 2), (False, False, False), (True, False))
+    ] == (80, ex.rows)
+
+
 # --------------------------------------------------------------------- #
 # Session-level backend parity
 # --------------------------------------------------------------------- #

@@ -107,7 +107,7 @@ def test_fused_range_count(n_bits, chunks):
                                                     (2, True)])
 def test_fused_predicate_banked_vs_ref(n_bits, chunks, shards, num_ranges,
                                        disjunction):
-    from repro.kernels.fused_query import fused_predicate_banked
+    from repro.kernels.fused_query import fused_predicate_banked, row_slabs
 
     plan = make_plan(n_bits, chunks)
     n, feats = 900, 3
@@ -129,8 +129,9 @@ def test_fused_predicate_banked_vs_ref(n_bits, chunks, shards, num_ranges,
                   lt[0] + (feats + fi) * r_pad,
                   lt[1] + (feats + fi) * r_pad]
     idx = jnp.asarray(np.concatenate(parts).astype(np.int32))
-    bm, cnt = fused_predicate_banked(lut, idx, chunks, num_ranges,
-                                     disjunction)
+    bm, cnt = fused_predicate_banked(row_slabs(lut), idx, chunks,
+                                     num_ranges, disjunction,
+                                     words=lut.shape[2])
     rbm, rcnt = ref.fused_predicate_banked_ref(lut, idx, chunks,
                                                num_ranges, disjunction)
     np.testing.assert_array_equal(np.asarray(bm), np.asarray(rbm))
@@ -147,6 +148,72 @@ def test_fused_predicate_banked_vs_ref(n_bits, chunks, shards, num_ranges,
         got = unpack_bits_jnp(bm[s], n).astype(bool)
         np.testing.assert_array_equal(np.asarray(got), want)
         assert int(cnt[s]) == int(want.sum())
+
+
+# Gather edge cases, through the executor's own layout and index lanes:
+# (n_bits, chunks, per-column plans, records, shards, ranges, OR,
+# VMEM budget for the gather buffers, None for the default).
+_GATHER_CASES = {
+    # one feature twice: every lane repeats; x0 = 0 and x1 = MAX
+    # resolve to constant rows
+    "repeated_and_const_rows": (16, 4, None, 900, 2,
+                                [(0, 0, 65535), (0, 0, 65535)], False,
+                                None),
+    # a 2-chunk 8-bit column padded to 4 chunks with identity lanes,
+    # its x1 past the column's max clamped to the constant-one row
+    "clamped_narrow_plan": (16, 4, [(8, 2), (16, 4)], 900, 2,
+                            [(0, 10, 40000), (1, 9000, 50000)], True,
+                            None),
+    # W = 384 words = 128 x 3, padded to a 1024-word slab
+    "odd_width": (16, 4, None, 12000, 1,
+                  [(0, 3000, 60000), (1, 100, 30000)], False, None),
+    # a budget of one 1024-word block for K = 16 lanes: two blocks a
+    # shard, so the double buffer crosses shards
+    "three_shards_two_blocks": (8, 2, None, 120000, 3,
+                                [(0, 30, 200), (1, 77, 250)], True,
+                                2 * 16 * 1024 * 4),
+    # one column: K = 24 lanes gather more rows than a shard's 16
+    "lanes_exceed_rows": (2, 2, [(2, 2)], 700, 2,
+                          [(0, 0, 2), (0, 1, 3), (0, 2, 3)], True, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_GATHER_CASES))
+def test_fused_predicate_banked_gather_cases(case, monkeypatch):
+    from repro.apps.predicate import Table
+    from repro.core.encoding import ColumnPlan
+    from repro.kernels import fused_query
+    from repro.kernels.fused_session import FusedTableExec
+
+    (n_bits, chunks, plans, n, shards, ranges, disj,
+     budget) = _GATHER_CASES[case]
+    if budget is not None:
+        monkeypatch.setattr(fused_query, "VMEM_TILE_BYTES", budget)
+    feats = 2 if plans is None else len(plans)
+    cols = [RNG.integers(0, 1 << (n_bits if plans is None else p[0]), n,
+                         dtype=np.uint32) for p in (plans or [None] * feats)]
+    ex = FusedTableExec(Table(n_bits=n_bits, features=cols),
+                        num_shards=shards, num_chunks=chunks,
+                        plans=None if plans is None else
+                        [ColumnPlan(*p) for p in plans])
+    idx = jnp.asarray(ex._indices(ranges))
+    # the oracle reads the plain [S, R, W] stack the slabs were made of
+    s, w = ex.num_shards, ex.words
+    lut = ex.lut.reshape(s, ex.rows, -1)[:, :, :w]
+    bm, cnt = fused_query.fused_predicate_banked(
+        ex.lut, idx, ex.num_chunks, len(ranges), disj, words=w)
+    rbm, rcnt = ref.fused_predicate_banked_ref(lut, idx, ex.num_chunks,
+                                               len(ranges), disj)
+    np.testing.assert_array_equal(np.asarray(bm), np.asarray(rbm))
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(rcnt))
+    want = None
+    for fi, x0, x1 in ranges:
+        v = cols[fi].astype(np.int64)
+        m = (v > x0) & (v < x1)
+        want = m if want is None else (want | m if disj else want & m)
+    got = unpack_bits_jnp(bm, ex.per).reshape(-1)[:n].astype(bool)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert int(cnt.sum()) == int(want.sum())
 
 
 @pytest.mark.parametrize("n_bits,chunks", [(8, 1), (16, 2), (32, 5)])

@@ -3,11 +3,12 @@
 Each test lowers a kernel with Mosaic (interpret mode steered off) for
 one chip of a described ``v5e:2x2`` topology and compiles it with the
 TPU compiler -- which refuses illegal block tiles, scalar reads from
-VMEM, unsigned reductions and VMEM or SMEM overflow, none of which the
-CPU interpreter checks.  Nothing runs.  Widths are ``chip_smoke.py``'s:
-a 16,777,216-record table of eight 16-bit columns in 8 record shards at
-4 chunks, and a 1000-tree depth-6 forest over 8 features of 8 bits
-scoring 4096 instances.
+VMEM, unsigned reductions, DMA slices off the tiling and VMEM or SMEM
+overflow, none of which the CPU interpreter checks.  Nothing runs.
+Widths are ``chip_smoke.py``'s: a 16,777,216-record table of eight
+16-bit columns in 8 record shards at 4 chunks (the predicate kernel's
+row slabs: ``[8, 1024 * 512, 128]``), and a 1000-tree depth-6 forest
+over 8 features of 8 bits scoring 4096 instances.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
@@ -23,6 +24,8 @@ from repro.core.encoding import make_plan
 from repro.kernels import clutch_merge, fused_query, ops, temporal_encode
 
 SHARDS, TABLE_ROWS, TABLE_WORDS, CHUNKS = 8, 1024, 65536, 4
+#: the stacked table LUT as ``fused_query.row_slabs`` lays it out
+TABLE_SLABS = (SHARDS, TABLE_ROWS * TABLE_WORDS // 128, 128)
 FOREST_ROWS, FOREST_WORDS, FEATURES, BATCH = 264, 256, 8, 4096
 
 
@@ -70,22 +73,22 @@ def _table_idx(num_ranges, spec):
 @pytest.mark.parametrize("num_ranges,disjunction",
                          [(1, False), (2, False), (2, True)])
 def test_fused_predicate_banked_compiles(mosaic, num_ranges, disjunction):
-    lut = mosaic((SHARDS, TABLE_ROWS, TABLE_WORDS), jnp.uint32)
+    lut = mosaic(TABLE_SLABS, jnp.uint32)
     text = _compile_text(
         lambda lut, idx: fused_query.fused_predicate_banked(
-            lut, idx, CHUNKS, num_ranges, disjunction),
+            lut, idx, CHUNKS, num_ranges, disjunction, words=TABLE_WORDS),
         lut, _table_idx(num_ranges, mosaic))
     assert "tpu_custom_call" in text
     assert "%clutch_predicate" in text      # the kernel's name in a trace
 
 
 def test_fused_compound_banked_compiles(mosaic):
-    # (Q1 AND Q2) OR Q3: terms of 1, 2 and 2 ranges
-    lut = mosaic((SHARDS, TABLE_ROWS, TABLE_WORDS), jnp.uint32)
+    # (Q1 AND Q2) OR Q3: terms of 1, 2 and 2 ranges, K = 80 lanes
+    lut = mosaic(TABLE_SLABS, jnp.uint32)
     text = _compile_text(
         lambda lut, idx: fused_query.fused_compound_banked(
             lut, idx, CHUNKS, (1, 2, 2), (False, False, True),
-            (False, True)),
+            (False, True), words=TABLE_WORDS),
         lut, _table_idx(5, mosaic))
     assert "tpu_custom_call" in text
     assert "%clutch_predicate" in text      # the kernel's name in a trace
