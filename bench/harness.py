@@ -7,7 +7,9 @@ gives it:
 
 * ``<config file>`` (from ``configs[].file``): sizes and ``kind``;
 * ``bench/kinds/<kind>.py``: data from the seed, the system under test,
-  the lower-precision control and the comparison with the reference;
+  the lower-precision control, the comparison with the reference, and
+  ``describe(req)``: the small value a per-layer reader takes in place
+  of a request (the window keeps that, not the request);
 * ``bench/traffic/<traffic>.json``: the mix's parameters, read by the
   generator ``bench/traffic/<generator>.py`` that it names;
 * ``bench/metrics/<metric>.py``: one reader per per-layer metric.
@@ -154,11 +156,13 @@ class Sampler:
         return [s for k in sorted(self.kept) for s in self.kept[k]]
 
 
-@dataclass
+@dataclass(slots=True)
 class Request:
-    """One request of the window as the host saw it."""
+    """One request of the window as the host saw it.  ``desc`` is the
+    kind module's ``describe(req)``, never the request itself, so that
+    the window's bookkeeping does not grow with the requests' payload."""
     i: int
-    req: object
+    desc: object
     latency_s: float
     wallclock_ns: float | None
     failed: bool = False
@@ -189,11 +193,12 @@ class Window:
         return float(peaks[self.device_kind][key])
 
 
-def drive(system, requests, seconds: float, kind_of, sampler: Sampler,
-          traced: bool) -> tuple[list[Request], float]:
+def drive(system, requests, seconds: float, kind: ModuleType,
+          sampler: Sampler, traced: bool) -> tuple[list[Request], float]:
     """The closed loop.  Returns the requests and the window's length:
     from its start to the end of the last request sent before its
-    close."""
+    close.  ``kind`` is the cell's kind module; only the sampler keeps
+    a request itself, with its result."""
     import jax
 
     out: list[Request] = []
@@ -214,9 +219,9 @@ def drive(system, requests, seconds: float, kind_of, sampler: Sampler,
                 traceback.print_exc()
                 failed = True
         t_end = time.perf_counter()
-        out.append(Request(i, req, t_end - t0, wall, failed))
+        out.append(Request(i, kind.describe(req), t_end - t0, wall, failed))
         if not failed:
-            sampler.offer(kind_of(req), (req, result))
+            sampler.offer(kind.request_kind(req), (req, result))
     return out, t_end - t_start
 
 
@@ -285,7 +290,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     try:
         reqs, span_s = drive(sut, traffic.requests(mix, config,
                                                    stream(seed, TRAFFIC)),
-                             seconds, kind.request_kind, sampler, trace)
+                             seconds, kind, sampler, trace)
     finally:
         counter.open = False
         if trace:

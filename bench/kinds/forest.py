@@ -33,6 +33,13 @@ def request_kind(req: np.ndarray) -> str:
     return "batch"
 
 
+def describe(req: np.ndarray) -> int:
+    """What the window keeps of a request: its batch size
+    (``bench.kernels.leafbits_bytes`` reads it), not the ``[B, F]``
+    instances."""
+    return len(req)
+
+
 class Program:
     """The system under test: a forest resource of a fused-backend
     session."""

@@ -38,6 +38,12 @@ def request_kind(req: tuple) -> str:
     return req[0]
 
 
+def describe(req: tuple) -> tuple:
+    """What the window keeps of a request: the tuple itself, a few
+    integers (``bench.kernels.predicate_bytes`` reads it)."""
+    return req
+
+
 def _query(req: tuple):
     from repro.pud import Q1, Q2, Q3, Q4, Q5
     from repro.pud.queries import Compound

@@ -65,6 +65,14 @@ class Reference:
             return 0.0
         return float(vals.mean(dtype=self.avg_dtype))
 
+    def bracket(self, fl: int, avg: int) -> int:
+        """Q5's count: records with ``avg < f_l < 2 * avg`` (clamped
+        to the key range), 0 where that range is empty."""
+        hi = min(2 * avg, (1 << self.n_bits) - 1)
+        if avg >= hi:
+            return 0
+        return int(self.where(fl, avg, hi).sum())
+
     def __call__(self, req: tuple):
         kind, *p = req
         if kind in ("q1", "q2"):
@@ -76,11 +84,8 @@ class Reference:
             return self.average(fk, self.term(("q2", *q2)))
         if kind == "q5":
             fl, fk, *q3 = p
-            avg = int(self.average(fk, self.term(("q3", *q3))))
-            hi = min(2 * avg, (1 << self.n_bits) - 1)
-            if avg >= hi:
-                return 0
-            return int(self.where(fl, avg, hi).sum())
+            return self.bracket(fl, int(self.average(
+                fk, self.term(("q3", *q3)))))
         if kind == "compound":
             ops, terms = p
             bm = self.term(terms[0])

@@ -5,77 +5,129 @@ its place, or with the timed path broken underneath by one fault.
     JAX_PLATFORMS=cpu python tests/bench/cpu_run.py ROOT CELL SEED SECONDS \\
         [--trace] [--control] [--fault NAME]
 
-Faults (each breaks what the program produces, where it produces it):
+Faults (each breaks what the program returns, at the harness's boundary
+with it: the cell's ``Program`` of ``bench/kinds/<kind>.py``; the wrong
+answer is worked out from the request, the cell's data and the NumPy
+reference in ``bench/ref/``, and touches nothing inside the program):
 
 * ``bitmap_bit``: one bit of every returned bitmap flipped;
 * ``count_off``: every count off by one;
 * ``half_batch``: half of the result left out -- the second half of
-  every bitmap cleared (so Q4's and Q5's averages are taken over the
-  rest) and the second half of every forest batch scored as the first;
-* ``shard_join``: the join of the shards' counts left out (only the
-  first shard's count returned);
-* ``leaf_addr``: one leaf address bit flipped in every forest batch.
+  every bitmap cleared, Q4's and Q5's averages taken over the records
+  that remain, and the second half of every forest batch scored as the
+  first;
+* ``shard_join``: the join of the shards' counts left out (Q3's and
+  Q5's counts over the first record shard's records only);
+* ``leaf_addr``: instance 0's prediction with one address bit of tree 0
+  flipped (``leaves[0, a ^ 1]`` in place of ``leaves[0, a]``).
 """
 
 from __future__ import annotations
 
 import argparse
+import math
 import sys
 from pathlib import Path
+
+import numpy as np
 
 REPO = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(REPO), str(REPO / "src")]
 
 
-def plant(fault: str) -> None:
-    from repro.kernels import fused_session as fs
+def _half(mask: np.ndarray) -> np.ndarray:
+    mask = mask.copy()
+    mask[mask.shape[0] // 2:] = False
+    return mask
 
-    if fault in ("bitmap_bit", "half_batch"):
-        orig_bitmap = fs.FusedTableExec._bitmap
 
-        def bitmap(self, bm):
-            out = orig_bitmap(self, bm).copy()
-            if fault == "bitmap_bit":
-                out[0] = not out[0]
-            else:
-                out[out.shape[0] // 2:] = False
-            return out
+class ScanFaults:
+    """The wrong answers of a scan cell's faults."""
 
-        fs.FusedTableExec._bitmap = bitmap
-    if fault == "count_off":
-        orig_one = fs.FusedTableExec._one
+    def __init__(self, config: dict, data: dict) -> None:
+        from bench.ref import scan
 
-        def one(self, q):
-            got = orig_one(self, q)
-            return got + 1 if isinstance(got, int) else got
+        cols, n = data["columns"], config["n_bits"]
+        per = math.ceil(config["records"] / (config["pud_devices"]
+                                             * config["shards_per_device"]))
+        self.ref = scan.Reference(cols, n)
+        self.first = scan.Reference([c[:per] for c in cols], n)
 
-        fs.FusedTableExec._one = one
-    if fault == "shard_join":
-        orig_fn = fs.FusedTableExec._fn
+    def bitmap_bit(self, req, got):
+        if isinstance(got, np.ndarray):
+            got = got.copy()
+            got[0] = not got[0]
+        return got
 
-        def fn(self, num_ranges, disjunction):
-            inner = orig_fn(self, num_ranges, disjunction)
+    def count_off(self, req, got):
+        return got + 1 if isinstance(got, (int, np.integer)) else got
 
-            def first_shard(lut, idx):
-                bm, _ = inner(lut, idx)
-                return bm, inner(lut[:1], idx)[1]
+    def half_batch(self, req, got):
+        if isinstance(got, np.ndarray):
+            return _half(got)
+        if req[0] == "q4":
+            fk, *q2 = req[1:]
+            return self.ref.average(fk, _half(self.ref.term(("q2", *q2))))
+        if req[0] == "q5":
+            fl, fk, *q3 = req[1:]
+            return self.ref.bracket(fl, int(self.ref.average(
+                fk, _half(self.ref.term(("q3", *q3))))))
+        return got
 
-            return first_shard
+    def shard_join(self, req, got):
+        if req[0] == "q3":
+            return int(self.first.term(req).sum())
+        if req[0] == "q5":
+            fl, fk, *q3 = req[1:]
+            return self.first.bracket(fl, int(self.ref.average(
+                fk, self.ref.term(("q3", *q3)))))
+        return got
 
-        fs.FusedTableExec._fn = fn
-    if fault in ("half_batch", "leaf_addr"):
-        orig_addrs = fs.FusedGbdtExec.leaf_addrs
 
-        def leaf_addrs(self, X):
-            out = orig_addrs(self, X).copy()
-            if fault == "leaf_addr":
-                out[0, 0] ^= 1
-            else:
-                half = out.shape[0] // 2
-                out[half:2 * half] = out[:half]
-            return out
+class ForestFaults:
+    """The wrong answers of a forest cell's faults."""
 
-        fs.FusedGbdtExec.leaf_addrs = leaf_addrs
+    def __init__(self, config: dict, data: dict) -> None:
+        self.forest = data["forest"]
+
+    def half_batch(self, X, got):
+        got = np.array(got, copy=True)
+        half = got.shape[0] // 2
+        got[half:2 * half] = got[:half]
+        return got
+
+    def leaf_addr(self, X, got):
+        from bench.ref import forest
+
+        a = int(forest.leaf_addrs(self.forest, X[:1])[0, 0])
+        leaves = self.forest.leaves
+        got = np.array(got, copy=True)
+        got[0] += leaves[0, a ^ 1] - leaves[0, a]
+        return got
+
+
+FAULTS = {"scan": ScanFaults, "forest": ForestFaults}
+
+
+def plant(fault: str, kind) -> None:
+    """Put a program whose results carry ``fault`` in place of the kind
+    module's ``Program``."""
+    program = kind.Program
+
+    class Faulty(program):
+        def __init__(self, config: dict, data: dict) -> None:
+            super().__init__(config, data)
+            faults = FAULTS[config["kind"]](config, data)
+            if not hasattr(faults, fault):
+                raise ValueError(f"a {config['kind']} cell has no fault "
+                                 f"{fault!r}")
+            self.fault = getattr(faults, fault)
+
+        def __call__(self, req):
+            got, wall = super().__call__(req)
+            return self.fault(req, got), wall
+
+    kind.Program = Faulty
 
 
 def main() -> int:
@@ -91,9 +143,9 @@ def main() -> int:
 
     from bench import harness
 
-    if args.fault:
-        plant(args.fault)
     cell = harness.resolve(args.cell, Path(args.root))
+    if args.fault:
+        plant(args.fault, cell.kind)
     result = harness.run(cell, args.seed, args.seconds, args.trace,
                          system="control" if args.control else "program")
     harness.report(result)

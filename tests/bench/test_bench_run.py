@@ -1,15 +1,24 @@
 """``bench/run.py`` refuses to run without a TPU and prints no result;
 a run of a tiny cell driven past that check on the CPU is correct and
-prints what the contract asks for."""
+prints what the contract asks for; the window's bookkeeping keeps no
+request's payload, and the roofline readers count the same bytes from
+what it keeps as from the requests."""
 
 import json
 import os
 import subprocess
 import sys
+import weakref
+from dataclasses import astuple
 from pathlib import Path
+from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
+from bench import harness, kernels
+from bench.trace import Interval, Trace
+from test_bench_trace import LEAF, PRED
 from tiny import make_root
 
 HERE = Path(__file__).resolve().parent
@@ -75,3 +84,82 @@ def test_traced_run_reads_per_layer_metrics(root, tmp_path):
     assert "setup_s" not in result["metrics"]
     assert result["device"]["window_s"] > 0
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def _kind(name):
+    return harness.load_module(ROOT / "bench" / "kinds" / f"{name}.py")
+
+
+def _config(name):
+    return json.loads((ROOT / "bench" / "configs" / f"{name}.json")
+                      .read_text())
+
+
+def test_drive_keeps_no_request_array():
+    """A forest cell's ``[4096, 8]`` int64 batches: what ``drive()``
+    returns holds none of them, and only the sampler's few stay alive."""
+    forest = _kind("forest")
+    made = []
+
+    def batches():
+        rng = np.random.default_rng(0)
+        while True:
+            X = rng.integers(0, 256, (4096, 8), dtype=np.int64)
+            made.append(weakref.ref(X))
+            yield X
+            del X
+
+    def system(X):
+        return np.zeros(len(X), np.float32), 1.0
+
+    sampler = harness.Sampler(3, harness.stream(1, harness.SAMPLE))
+    gen = batches()
+    reqs, span_s = harness.drive(system, gen, 0.3, forest, sampler, False)
+    gen.close()
+    assert len(reqs) > 10 and span_s > 0
+    for r in reqs:
+        assert not any(isinstance(v, np.ndarray) for v in astuple(r))
+        assert r.desc == 4096
+    assert sum(ref() is not None for ref in made) == len(sampler.samples) == 3
+    assert [r.i for r in reqs] == list(range(len(reqs)))
+
+
+def _window(desc, label, config):
+    """Two traced requests, each with one kernel launch of 20 ns."""
+    tr = Trace(device={"/device:TPU:0": [Interval(10, 30, label),
+                                         Interval(60, 80, label)]},
+               spans=[Interval(0, 40, "bench_request", (("i", "0"),)),
+                      Interval(50, 100, "bench_request", (("i", "1"),))])
+    reqs = [harness.Request(i, d, 1e-3, None) for i, d in enumerate(desc)]
+    return harness.Window(reqs, tr, 0, 100, SimpleNamespace(config=config),
+                          "TPU v5 lite")
+
+
+R = (0, 10, 20)
+
+
+@pytest.mark.parametrize("metric, kind, config, requests, label", [
+    ("predicate_roofline", "scan", "scan16x8",
+     [("q3", *R, 1, 5, 9), ("compound", ("and", "or"),
+                            (("q1", *R), ("q2", *R, *R), ("q3", *R, *R)))],
+     PRED),
+    ("leafbits_roofline", "forest", "forest_cb1000",
+     [np.zeros((4096, 8), np.int64), np.ones((64, 8), np.int64)], LEAF),
+])
+def test_roofline_bytes_from_the_descriptor(metric, kind, config, requests,
+                                            label):
+    """A reader given the descriptors reads what the byte counts of the
+    requests themselves give, as before the window kept descriptors."""
+    cfg, k = _config(config), _kind(kind)
+    if kind == "scan":
+        want = [kernels.predicate_bytes(cfg, req) for req in requests]
+        got = [kernels.predicate_bytes(cfg, k.describe(req))
+               for req in requests]
+    else:
+        want = [kernels.leafbits_bytes(cfg, len(X)) for X in requests]
+        got = [kernels.leafbits_bytes(cfg, k.describe(X)) for X in requests]
+    assert got == want
+    reader = harness.load_module(ROOT / "bench" / "metrics" / f"{metric}.py")
+    w = _window([k.describe(req) for req in requests], label, cfg)
+    assert reader.read(w) == pytest.approx(
+        100.0 * sum(want) / 819e9 / 40e-9, rel=1e-12)
