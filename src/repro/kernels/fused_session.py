@@ -46,7 +46,8 @@ operands, so ONE compiled executable per ``(plan, table shape, query
 kind)`` serves every (feature, scalar) combination.  ``trace_counts``
 exposes the per-kind trace counter the zero-retrace regression test
 asserts on; ``lut_rows_read`` the rows a kind's launch gathers per
-shard against the rows a shard holds (a Q3 at 4 chunks: 32).
+shard against the rows a shard holds (a Q3 at 4 chunks: 32);
+``FusedGbdtExec.leafbits_launch`` what one leaf-bits launch covers.
 
 Heterogeneous per-column plans: ``plans`` (one
 :class:`~repro.core.encoding.ColumnPlan` per feature) stacks RAGGED
@@ -113,6 +114,12 @@ SPAN_DISPATCH = "clutch.dispatch"
 SPAN_READBACK = "clutch.readback"
 SPAN_UNPACK = "clutch.unpack"
 SPAN_FINISH = "clutch.finish"
+
+#: Bytes of the int64 product the leaf-address sum forms per block of
+#: instances: a few MiB the heap hands back every block, so that no
+#: ``[B, T, depth]`` product (196 MB for 4096 instances of 1000 depth-6
+#: trees) is mapped and page-faulted afresh every request.
+FINISH_BLOCK_BYTES = 8 << 20
 
 
 class FusedTableExec:
@@ -445,15 +452,24 @@ class FusedGbdtExec:
         self.lut = jax.device_put(self.lut, rep)
         self.masks = jax.device_put(jnp.asarray(masks), rep)
         self.trace_counts: dict[tuple, int] = {}
+        #: (padded instances a device's launch scores, leaf-bit words
+        #: ``ceil(trees * depth / 32)``, LUT rows with the constant
+        #: rows, features) of the launch, set when it is traced: a new
+        #: batch shape retraces and replaces it.
+        self.leafbits_launch: dict[str, tuple[int, int, int, int]] = {}
         self._fn_cached = None
 
     def _fn(self):
         if self._fn_cached is None:
             c, f = self.num_chunks, self.forest.num_features
+            words = math.ceil(self.n_nodes / 32)
+            rows = self.plan.rows_required + 2
 
             def local(lut, masks, idx):
                 self.trace_counts["gbdt"] = \
                     self.trace_counts.get("gbdt", 0) + 1
+                self.leafbits_launch["gbdt"] = (int(idx.shape[0]), words,
+                                                rows, f)
                 return gbdt_leafbits_banked(lut, masks, idx, c, f)
 
             axis = "shards"
@@ -492,7 +508,12 @@ class FusedGbdtExec:
             bits = bits.reshape(b_pad, forest.num_trees, forest.depth)
         with TraceAnnotation(SPAN_FINISH):
             weights = 1 << np.arange(forest.depth)[::-1]
-            return (bits * weights).sum(-1).astype(np.int32)[:b]
+            bits = bits[:b]
+            addrs = np.empty((b, forest.num_trees), np.int32)
+            step = max(1, FINISH_BLOCK_BYTES // (8 * self.n_nodes))
+            for i in range(0, b, step):
+                addrs[i:i + step] = (bits[i:i + step] * weights).sum(-1)
+            return addrs
 
     def infer(self, X: np.ndarray) -> np.ndarray:
         """[B, F] -> [B] float32 predictions, bit-exact vs the machine

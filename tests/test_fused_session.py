@@ -20,7 +20,10 @@ Covered here:
   subprocess (``XLA_FLAGS=--xla_force_host_platform_device_count`` is
   never set in-process -- conftest must stay device-count-neutral);
 * the serving front end on a fused session, and fused-cache
-  invalidation on drop/evict.
+  invalidation on drop/evict;
+* CatBoost's default forest shape (1000 depth-6 trees, 8-bit features)
+  against the benchmark's NumPy reference, and the leaf-bits launch
+  counter against the benchmark's byte count.
 """
 
 import os
@@ -38,9 +41,17 @@ from repro.apps import predicate as P
 from repro.core import cost
 from repro.core.encoding import make_plan
 from repro.kernels import ops
+from repro.kernels import fused_session as FS
 from repro.kernels.fused_session import FusedGbdtExec, FusedTableExec
 from repro.pud import Q1, Q2, Q3, Q4, Q5, PudSession
 from repro.serve.pud_service import PudRequest, PudService
+
+# the benchmark's package (``bench``) lives at the repository's root
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+from bench import harness, kernels  # noqa: E402
+from bench.ref import forest as bench_forest  # noqa: E402
 
 MX = 255
 QA = dict(fi=0, x0=MX // 8, x1=MX // 2, fj=1, y0=MX // 4, y1=3 * MX // 4)
@@ -101,7 +112,7 @@ def test_fused_gbdt_exec_parity_property(seed):
     n_bits = int(rng.choice([8, 16]))
     forest = G.ObliviousForest.random(
         num_trees=int(rng.integers(2, 20)),
-        depth=int(rng.integers(2, 6)),
+        depth=int(rng.integers(2, 7)),
         num_features=int(rng.integers(2, 6)),
         n_bits=n_bits, seed=seed)
     ex = FusedGbdtExec(forest, num_chunks=max(1, n_bits // 8))
@@ -112,6 +123,21 @@ def test_fused_gbdt_exec_parity_property(seed):
                                   G.reference_leaf_addrs(forest, X))
     np.testing.assert_allclose(ex.infer(X),
                                G.reference_predict(forest, X), atol=1e-5)
+
+
+@pytest.mark.parametrize("depth,rows", [(1, 1), (9, 5), (12, 64)])
+def test_fused_leaf_addrs_in_blocks(monkeypatch, depth, rows):
+    """The leaf-address sum runs ``rows`` instances a block: one row a
+    block, a ragged last block, and one block for the whole batch, over
+    a lone level and addresses wider than a byte."""
+    monkeypatch.setattr(FS, "FINISH_BLOCK_BYTES", 8 * 3 * depth * rows)
+    forest = G.ObliviousForest.random(num_trees=3, depth=depth,
+                                      num_features=4, n_bits=8, seed=depth)
+    X = np.random.default_rng(depth).integers(0, 256, (13, 4),
+                                              dtype=np.int64)
+    addrs = FusedGbdtExec(forest, num_chunks=1).leaf_addrs(X)
+    assert addrs.dtype == np.int32
+    np.testing.assert_array_equal(addrs, G.reference_leaf_addrs(forest, X))
 
 
 def test_fused_table_exec_empty_selection_and_always_true():
@@ -231,6 +257,67 @@ def test_repeated_predict_retraces_zero_times():
     # same padded batch shape, new values -> zero new traces
     s.predict(h, rng.integers(0, 256, (6, 3), dtype=np.uint64))
     assert fx.trace_counts == {"gbdt": 1}
+
+
+# --------------------------------------------------------------------- #
+# CatBoost's default forest shape, and the leaf-bits launch counter
+# --------------------------------------------------------------------- #
+
+def fused_forest(trees, depth, features, n_bits, seed):
+    """A fused session holding a random forest at 1 chunk, as the
+    forest cells load it: (session, handle, forest)."""
+    forest = G.ObliviousForest.random(trees, depth, features, n_bits,
+                                      seed=seed)
+    s = session(backend="fused")
+    return s, s.load_forest(forest, name="f", num_chunks=1), forest
+
+
+@pytest.fixture(scope="module")
+def catboost_default_forest():
+    """CatBoost's training defaults: 1000 oblivious trees of depth 6
+    over 8-bit (``border_count=254``) features; 8 features."""
+    return fused_forest(1000, 6, 8, 8, seed=17)
+
+
+@pytest.mark.parametrize("batch", [8, 13])
+def test_catboost_default_forest_matches_references(catboost_default_forest,
+                                                    batch):
+    """A whole 8-instance block and a ragged one at the published
+    shape: leaf addresses exact, predictions against both references."""
+    s, h, forest = catboost_default_forest
+    X = np.random.default_rng(batch).integers(0, 256, (batch, 8),
+                                              dtype=np.int64)
+    job = s.predict(h, X)
+    np.testing.assert_array_equal(s._fused["f"].leaf_addrs(X),
+                                  G.reference_leaf_addrs(forest, X))
+    # the benchmark's limit (bench/kinds/forest.py): above the ~1e-4 by
+    # which two float32 orders of 1000 unit-scale leaves differ
+    want = bench_forest.Reference(forest)(X)
+    np.testing.assert_allclose(job.result, want, rtol=0, atol=1e-3)
+    # reference_predict sums each instance's leaves in the served
+    # path's own float32 order, so only rounding of equal sums is left
+    np.testing.assert_allclose(job.result, G.reference_predict(forest, X),
+                               rtol=0, atol=1e-5)
+
+
+def test_leafbits_launch_counts_what_the_roofline_reads():
+    """One predict of a ragged batch at the benchmark's tiny forest
+    size: the counter holds the padded batch, the leaf-bit words, the
+    LUT rows and the features, and the bytes they give are what
+    ``bench.kernels.leafbits_bytes`` counts for that batch."""
+    cfg = harness.load_module(ROOT / "tests" / "bench" / "tiny.py").FOREST
+    s, h, _ = fused_forest(cfg["trees"], cfg["depth"], cfg["features"],
+                           cfg["n_bits"], seed=3)
+    X = np.random.default_rng(4).integers(0, 256, (13, cfg["features"]),
+                                          dtype=np.int64)
+    s.predict(h, X)
+    launch = s._fused["f"].leafbits_launch
+    # 40 trees x 6 levels = 240 bits in 8 words; 255 planes + 2 rows
+    assert launch == {"gbdt": (16, 8, 257, 8)}
+    batch, words, rows, features = launch["gbdt"]
+    idx = batch * features * 2 * cfg["num_chunks"]
+    need = (rows * words + features * words + idx + batch * words) * 4
+    assert need == kernels.leafbits_bytes(cfg, batch)
 
 
 def test_drop_and_evict_invalidate_fused_cache():
