@@ -127,12 +127,17 @@ def fit_oblivious_forest(X: np.ndarray, y: np.ndarray, num_trees: int,
 
 
 def assemble_leaves(leaves: np.ndarray, addrs: np.ndarray) -> np.ndarray:
-    """Host-side leaf assembly shared by the machine and fused backends:
-    ``leaves`` [T, L] float32, ``addrs`` [B, T] -> [B] float32 per-
-    instance sums.  Both backends MUST use this exact expression --
-    float32 summation order is part of the bit-exact parity contract."""
+    """Host-side leaf assembly of the machine backend: ``leaves`` [T, L]
+    float32, ``addrs`` [B, T] -> [B] float32 per-instance sums.  Each
+    instance's leaf values are summed as one contiguous float32 row,
+    NumPy's pairwise order -- the order the fused backend's device sum
+    (:func:`repro.kernels.fused_query.pairwise_sum_f32`) reproduces,
+    so float32 summation order is part of the bit-exact parity
+    contract.  The row is made contiguous first: a strided row would
+    be summed one term after another, another order."""
     t = leaves.shape[0]
-    return leaves[np.arange(t)[None], addrs].sum(-1).astype(np.float32)
+    per_tree = np.ascontiguousarray(leaves[np.arange(t)[None], addrs])
+    return per_tree.sum(-1).astype(np.float32)
 
 
 def reference_leaf_addrs(forest: ObliviousForest, X: np.ndarray

@@ -27,6 +27,11 @@ threshold comparison (per-instance gather indices, like the banked
 machine's broadcast wave with per-bank lookups) through the one-hot
 feature masks into the leaf-address bitmap rows -- the whole per-wave
 compute loop of :class:`repro.apps.gbdt.GbdtPudEngine` as one kernel.
+``gbdt_leaf_sum`` finishes the scoring on the device: per 128
+instances, each (tree, instance) leaf address picks its tree's leaf
+value by a lane gather within the tree's row of leaves (exact, no
+arithmetic), and the values are summed over the trees in
+:func:`pairwise_sum_f32`'s order, NumPy's float32 row-sum order.
 
 Memory placement.  The predicate kernel's stacked LUT stays in HBM
 (``memory_space=pl.ANY``) as :func:`row_slabs` lays it out: row ``i``
@@ -338,3 +343,75 @@ def gbdt_leafbits_banked(lut: jnp.ndarray, masks: jnp.ndarray,
         name="clutch_leafbits",
     )(idx, lut, masks)
     return bm[:b]
+
+
+def pairwise_sum_f32(values: jnp.ndarray) -> jnp.ndarray:
+    """``[n, b]`` float32 -> ``[1, b]``: each column summed in NumPy's
+    pairwise order, the order of a float32 row sum (``np.add.reduce``
+    over a contiguous row, what :func:`repro.apps.gbdt.assemble_leaves`
+    computes), so a device sum equals the host's bit for bit.
+
+    That order: under 8 terms, one after another; up to 128, eight
+    strided accumulators, combined ``((r0+r1)+(r2+r3))+((r4+r5)+(r6+r7))``,
+    then the terms past the last multiple of 8; above 128, the sums of
+    the first ``n//2 - (n//2) % 8`` terms and of the rest.  Every split
+    falls on a multiple of 8, so each ``[8, b]`` slice is one row of
+    whole ``(8, 128)`` tiles; the columns (a batch) lie on the lanes."""
+    n = values.shape[0]
+    if n > 128:
+        half = n // 2 - (n // 2) % 8
+        return pairwise_sum_f32(values[:half]) + \
+            pairwise_sum_f32(values[half:])
+    if n < 8:
+        res = values[0:1]
+        for i in range(1, n):
+            res = res + values[i:i + 1]
+        return res
+    whole = n - n % 8
+    r = values[:8]
+    for i in range(8, whole, 8):
+        r = r + values[i:i + 8]
+    res = (((r[0:1] + r[1:2]) + (r[2:3] + r[3:4])) +
+           ((r[4:5] + r[5:6]) + (r[6:7] + r[7:8])))
+    for i in range(whole, n):
+        res = res + values[i:i + 1]
+    return res
+
+
+def _leafsum_kernel(addr_ref, leaves_ref, out_ref):
+    addrs = addr_ref[...]                      # [T, 128] int32
+    leaves = leaves_ref[...]                   # [T, Lp] float32
+    # each tree's leaf row is its own lane table: an exact lane gather
+    # per 128 leaves, the addressed chunk's kept by a select
+    lane = addrs % LANES
+    vals = jnp.take_along_axis(leaves[:, :LANES], lane, axis=1)
+    for c in range(1, leaves.shape[1] // LANES):
+        part = jnp.take_along_axis(leaves[:, c * LANES:(c + 1) * LANES],
+                                   lane, axis=1)
+        vals = jnp.where(addrs // LANES == c, part, vals)
+    out_ref[...] = pairwise_sum_f32(vals)
+
+
+def gbdt_leaf_sum(addrs: jnp.ndarray, leaves: jnp.ndarray) -> jnp.ndarray:
+    """Predictions from leaf addresses: ``addrs`` [T, b] int32 (trees on
+    sublanes, instances on lanes) and ``leaves`` [T, Lp] float32 (each
+    tree's leaf values, ``Lp`` a multiple of 128) -> [b] float32, each
+    instance's addressed leaf values summed over the trees in
+    :func:`pairwise_sum_f32`'s order.  Each grid step takes 128
+    instances; the lookup is exact (a lane gather, no arithmetic)."""
+    t, b = addrs.shape
+    tl, lp = leaves.shape
+    assert tl == t and lp % LANES == 0, (addrs.shape, leaves.shape)
+    b_pad = round_up(max(b, 1), LANES)
+    addrs = jnp.pad(addrs.astype(jnp.int32), ((0, 0), (0, b_pad - b)))
+    out = pl.pallas_call(
+        _leafsum_kernel,
+        grid=(b_pad // LANES,),
+        in_specs=[pl.BlockSpec((t, LANES), lambda j: (0, j)),
+                  pl.BlockSpec((t, lp), lambda j: (0, 0))],
+        out_specs=pl.BlockSpec((1, LANES), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, b_pad), jnp.float32),
+        interpret=use_interpret(),
+        name="clutch_leafsum",
+    )(addrs, leaves)
+    return out[0, :b]

@@ -24,20 +24,26 @@ the machine path's semantics exactly:
   :func:`~repro.kernels.fused_query.fused_compound_banked` -- one
   launch per compound, the register-level mirror of the machine path's
   in-bank Ambit AND/OR merge (one executable per compound *shape*).
-* :class:`FusedGbdtExec` -- GBDT inference.  The forest's threshold LUT
-  and one-hot feature masks are device-resident; one
+* :class:`FusedGbdtExec` -- GBDT inference.  The forest's threshold LUT,
+  one-hot feature masks and leaf values are device-resident; ONE jitted
+  program goes from the instances' row indices to their ``[B]`` float32
+  predictions, sharded over the mesh on the instance axis.  Each device
+  streams its instances in blocks (:func:`score_block`): a
   :func:`~repro.kernels.fused_query.gbdt_leafbits_banked` grid over
-  *(instance, word block)* folds every feature comparison into each
-  instance's leaf-address bitmap, sharded over the mesh on the instance
-  axis.
+  *(instance, word block)* folds every feature comparison into the
+  block's leaf-address bitmaps, which the same program unpacks into
+  ``[trees, block]`` leaf addresses; one
+  :func:`~repro.kernels.fused_query.gbdt_leaf_sum` grid over 128-instance
+  lane rows then looks each address's leaf value up and sums them.
 
 Bit-exact parity contract (tested in ``tests/test_fused_session.py``):
 bitmaps, counts and leaf addresses are exact integer/boolean math on
-device; the few FLOAT aggregates (Q4/Q5 averages, GBDT leaf sums) are
-finished HOST-side with the same NumPy expressions the machine
-executors use (:func:`repro.apps.gbdt.assemble_leaves` is shared), so
-summation order -- and therefore every result -- is identical to
-``backend="machine"``.
+device.  Q4/Q5 averages are finished HOST-side with the same NumPy
+expression the machine executors use.  GBDT leaf sums run on the device
+in the float32 order :func:`~repro.kernels.fused_query.pairwise_sum_f32`
+defines, which is NumPy's pairwise row sum: the order of
+:func:`repro.apps.gbdt.assemble_leaves`, the machine executors' host
+sum, so every prediction is identical to ``backend="machine"``.
 
 Compile-cache invariant: feature indices and scalars are resolved to
 row-index *arrays* (host-side, memoized via
@@ -76,8 +82,10 @@ executors open, per launch, ``SPAN_RESOLVE`` (Algorithm 1 row indices on
 the host), ``SPAN_DISPATCH`` (indices to the device and the async
 launch), ``SPAN_READBACK`` (the first blocking read of a result) and,
 where a bitmap comes back, ``SPAN_UNPACK`` (words to bits) and
-``SPAN_FINISH`` (Q4/Q5 averages, leaf addresses and leaf sums).  With
-the profiler off a span costs under a microsecond of host time.
+``SPAN_FINISH`` (Q4/Q5 averages).  A forest request opens resolve,
+dispatch and readback alone: what it reads back is the ``[B]``
+predictions.  With the profiler off a span costs under a microsecond of
+host time.
 """
 
 from __future__ import annotations
@@ -94,10 +102,11 @@ from repro.core.encoding import ChunkPlan, ColumnPlan, make_plan
 from repro.core.machine import pack_bits, unpack_bits
 from repro.dist.sharding import shard_mesh
 
-from .common import SUBLANES, round_up
+from .common import LANES, SUBLANES, round_up
 from .fused_query import (
     fused_compound_banked,
     fused_predicate_banked,
+    gbdt_leaf_sum,
     gbdt_leafbits_banked,
     row_slabs,
 )
@@ -115,11 +124,22 @@ SPAN_READBACK = "clutch.readback"
 SPAN_UNPACK = "clutch.unpack"
 SPAN_FINISH = "clutch.finish"
 
-#: Bytes of the int64 product the leaf-address sum forms per block of
-#: instances: a few MiB the heap hands back every block, so that no
-#: ``[B, T, depth]`` product (196 MB for 4096 instances of 1000 depth-6
-#: trees) is mapped and page-faulted afresh every request.
-FINISH_BLOCK_BYTES = 8 << 20
+#: Bytes of the ``[trees, instances]`` int32 leaf addresses one scoring
+#: block forms on the device (:func:`score_block`): 512 instances of a
+#: 1000-tree forest, so that no ``[B, trees]`` array of a whole batch is
+#: ever held.
+SCORE_BLOCK_BYTES = 2 << 20
+
+
+def score_block(trees: int, batch: int) -> int:
+    """Instances one scoring block holds: :data:`SCORE_BLOCK_BYTES` of
+    ``[trees, block]`` addresses, in whole 128-instance lane rows of the
+    leaf-sum kernel where that budget holds one, else in whole
+    8-instance leaf-bits kernel blocks; at least one such block, at most
+    the padded per-device ``batch``."""
+    fit = SCORE_BLOCK_BYTES // (4 * trees)
+    unit = LANES if fit >= LANES else SUBLANES
+    return min(max(fit // unit * unit, SUBLANES), batch)
 
 
 class FusedTableExec:
@@ -403,16 +423,18 @@ class FusedTableExec:
 
 
 class FusedGbdtExec:
-    """One-jit GBDT leaf-address computation for a whole batch.
+    """One-jit GBDT inference for a whole batch.
 
     ``forest`` is duck-typed (``thresholds``, ``feature_idx``,
     ``leaves``, ``n_bits``, ``num_features``, ``num_trees``, ``depth``).
-    The device half (comparisons, masking, OR-accumulation into the
-    leaf-address bitmap) is exact integer math in one kernel grid over
-    *(instance, word block)*, sharded over the mesh on the instance
-    axis; leaf gathering/summation reuses the machine path's
-    :func:`repro.apps.gbdt.assemble_leaves` so predictions are
-    bit-exact vs ``backend="machine"``."""
+    One program, sharded over the mesh on the instance axis, scores each
+    device's instances a block at a time: the leaf-bits kernel
+    (comparisons, masking, OR-accumulation into the leaf-address bitmap;
+    exact integer math), the leaf addresses unpacked from the bitmap,
+    and the leaf-sum kernel (the addressed leaf values, looked up
+    exactly, summed in
+    :func:`~repro.kernels.fused_query.pairwise_sum_f32`'s float32
+    order), so predictions are bit-exact vs ``backend="machine"``."""
 
     def __init__(self, forest, num_chunks: int, mesh=None,
                  plan=None) -> None:
@@ -451,37 +473,87 @@ class FusedGbdtExec:
         rep = NamedSharding(self.mesh, P())
         self.lut = jax.device_put(self.lut, rep)
         self.masks = jax.device_put(jnp.asarray(masks), rep)
-        self.trace_counts: dict[tuple, int] = {}
-        #: (padded instances a device's launch scores, leaf-bit words
-        #: ``ceil(trees * depth / 32)``, LUT rows with the constant
-        #: rows, features) of the launch, set when it is traced: a new
-        #: batch shape retraces and replaces it.
+        t, d = forest.num_trees, forest.depth
+        # each tree's leaf values on one row of whole 128-lane tiles
+        leaves = np.zeros((t, round_up(1 << d, LANES)), np.float32)
+        leaves[:, :1 << d] = np.asarray(forest.leaves, np.float32)
+        self.leaves = jax.device_put(jnp.asarray(leaves), rep)
+        # node n = t * depth + k sits at bit n % 32 of word n // 32
+        nodes = np.arange(t)[:, None] * d + np.arange(d)[None, :]
+        self._node_word = (nodes // 32).T.astype(np.int32)      # [D, T]
+        self._node_bit = (nodes % 32).T.astype(np.uint32)       # [D, T]
+        self.trace_counts: dict[str, int] = {}
+        #: (padded instances a leaf-bits launch scores -- one block --,
+        #: leaf-bit words ``ceil(trees * depth / 32)``, LUT rows with the
+        #: constant rows, features), per program, set when it is traced:
+        #: a new batch shape retraces and replaces it.
         self.leafbits_launch: dict[str, tuple[int, int, int, int]] = {}
-        self._fn_cached = None
+        #: (padded instances a device scores, instances a block, trees,
+        #: leaves a tree) per program, set when it is traced: the
+        #: device-side scoring's shape.
+        self.scoring_launch: dict[str, tuple[int, int, int, int]] = {}
+        self._fns: dict[str, object] = {}
 
-    def _fn(self):
-        if self._fn_cached is None:
+    def _addresses(self, bm: jnp.ndarray) -> jnp.ndarray:
+        """``[b, W]`` leaf-bit words -> ``[T, b]`` int32 leaf addresses,
+        depth 0 the most significant bit."""
+        words = bm.T                                            # [W, b]
+        addrs = jnp.zeros((self.forest.num_trees, bm.shape[0]), jnp.int32)
+        for w, bit in zip(self._node_word, self._node_bit):
+            level = (words[w] >> bit[:, None]) & jnp.uint32(1)
+            addrs = (addrs << 1) | level.astype(jnp.int32)
+        return addrs
+
+    def _fn(self, kind: str):
+        """The compiled program of ``kind``: ``"gbdt"`` returns each
+        instance's float32 prediction, ``"leaf_addrs"`` its ``[T]``
+        leaf addresses.  Each device runs the leaf-bits kernel and the
+        finish one block of its instances at a time; a batch that is not
+        a whole number of blocks ends with a block shifted back to end
+        at the batch's end, whose overlap is scored twice, alike."""
+        fn = self._fns.get(kind)
+        if fn is None:
             c, f = self.num_chunks, self.forest.num_features
+            t, n_leaves = self.forest.num_trees, 1 << self.forest.depth
             words = math.ceil(self.n_nodes / 32)
             rows = self.plan.rows_required + 2
 
-            def local(lut, masks, idx):
-                self.trace_counts["gbdt"] = \
-                    self.trace_counts.get("gbdt", 0) + 1
-                self.leafbits_launch["gbdt"] = (int(idx.shape[0]), words,
-                                                rows, f)
-                return gbdt_leafbits_banked(lut, masks, idx, c, f)
+            def local(lut, masks, leaves, idx):
+                n = int(idx.shape[0])
+                blk = score_block(t, n)
+                self.trace_counts[kind] = self.trace_counts.get(kind, 0) + 1
+                self.leafbits_launch[kind] = (blk, words, rows, f)
+                self.scoring_launch[kind] = (n, blk, t, n_leaves)
+
+                def step(i, out):
+                    start = jnp.minimum(i * blk, n - blk)
+                    bm = gbdt_leafbits_banked(
+                        lut, masks,
+                        jax.lax.dynamic_slice_in_dim(idx, start, blk), c, f)
+                    addrs = self._addresses(bm)                 # [T, blk]
+                    if kind == "leaf_addrs":
+                        res = addrs.T
+                    else:
+                        res = gbdt_leaf_sum(addrs, leaves)
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        out, res, start, axis=0)
+
+                shape = (n, t) if kind == "leaf_addrs" else (n,)
+                dtype = jnp.int32 if kind == "leaf_addrs" else jnp.float32
+                return jax.lax.fori_loop(0, -(-n // blk), step,
+                                         jnp.zeros(shape, dtype))
 
             axis = "shards"
-            self._fn_cached = jax.jit(jax.shard_map(
+            fn = jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
-                in_specs=(P(), P(), P(axis)), out_specs=P(axis),
+                in_specs=(P(), P(), P(), P(axis)), out_specs=P(axis),
                 check_vma=False))
-        return self._fn_cached
+            self._fns[kind] = fn
+        return fn
 
-    def leaf_addrs(self, X: np.ndarray) -> np.ndarray:
-        """[B, F] quantized instances -> [B, T] int32 leaf addresses
-        (exact; the whole device half of inference)."""
+    def _run(self, kind: str, X: np.ndarray) -> np.ndarray:
+        """Resolve ``X``'s row indices on the host, run ``kind``'s
+        program and read its result back, one row per instance."""
         forest, plan = self.forest, self.plan
         X = np.asarray(X)
         b = X.shape[0]
@@ -500,29 +572,20 @@ class FusedGbdtExec:
                 cols += [lt, le]
             idx = np.concatenate(cols, axis=1).astype(np.int32)
         with TraceAnnotation(SPAN_DISPATCH):
-            bm = self._fn()(self.lut, self.masks, jnp.asarray(idx))
+            out = self._fn(kind)(self.lut, self.masks, self.leaves,
+                                 jnp.asarray(idx))
         with TraceAnnotation(SPAN_READBACK):
-            words = np.asarray(bm)
-        with TraceAnnotation(SPAN_UNPACK):
-            bits = unpack_bits(words, self.n_nodes)        # [B_pad, nodes]
-            bits = bits.reshape(b_pad, forest.num_trees, forest.depth)
-        with TraceAnnotation(SPAN_FINISH):
-            weights = 1 << np.arange(forest.depth)[::-1]
-            bits = bits[:b]
-            addrs = np.empty((b, forest.num_trees), np.int32)
-            step = max(1, FINISH_BLOCK_BYTES // (8 * self.n_nodes))
-            for i in range(0, b, step):
-                addrs[i:i + step] = (bits[i:i + step] * weights).sum(-1)
-            return addrs
+            return np.asarray(out)[:b]
+
+    def leaf_addrs(self, X: np.ndarray) -> np.ndarray:
+        """[B, F] quantized instances -> [B, T] int32 leaf addresses,
+        as the scoring program computes them on the device (exact)."""
+        return self._run("leaf_addrs", X)
 
     def infer(self, X: np.ndarray) -> np.ndarray:
-        """[B, F] -> [B] float32 predictions, bit-exact vs the machine
-        executors (shared host-side leaf assembly)."""
-        from repro.apps.gbdt import assemble_leaves
-
+        """[B, F] -> [B] float32 predictions, scored on the device and
+        bit-exact vs the machine executors."""
         X = np.asarray(X)
         if X.shape[0] == 0:
             return np.empty((0,), np.float32)
-        addrs = self.leaf_addrs(X)
-        with TraceAnnotation(SPAN_FINISH):
-            return assemble_leaves(self.forest.leaves, addrs)
+        return self._run("gbdt", X)

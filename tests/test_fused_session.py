@@ -9,9 +9,9 @@ Covered here:
   :class:`FusedGbdtExec` over random plans, chunk counts, shard counts
   and table sizes (hypothesis, CPU interpret mode);
 * session-level machine-vs-fused equality for every query kind and for
-  predictions (predictions exact vs machine -- shared
-  ``assemble_leaves`` float summation order -- and allclose vs
-  ``reference_predict``, whose axis order differs);
+  predictions (exact vs machine: the device sums leaves in
+  ``pairwise_sum_f32``'s order, which is ``assemble_leaves``' NumPy
+  row-sum order, pinned per forest size);
 * the compile-cache invariant: repeated jobs -- including Q5's phase-2
   re-query with brand-new scalars -- re-trace ZERO times;
 * host-side resolver memoization (``resolve_indices`` lru cache, the
@@ -40,7 +40,7 @@ from repro.apps import gbdt as G
 from repro.apps import predicate as P
 from repro.core import cost
 from repro.core.encoding import make_plan
-from repro.kernels import ops
+from repro.kernels import fused_query, ops
 from repro.kernels import fused_session as FS
 from repro.kernels.fused_session import FusedGbdtExec, FusedTableExec
 from repro.pud import Q1, Q2, Q3, Q4, Q5, PudSession
@@ -125,19 +125,51 @@ def test_fused_gbdt_exec_parity_property(seed):
                                G.reference_predict(forest, X), atol=1e-5)
 
 
-@pytest.mark.parametrize("depth,rows", [(1, 1), (9, 5), (12, 64)])
-def test_fused_leaf_addrs_in_blocks(monkeypatch, depth, rows):
-    """The leaf-address sum runs ``rows`` instances a block: one row a
-    block, a ragged last block, and one block for the whole batch, over
-    a lone level and addresses wider than a byte."""
-    monkeypatch.setattr(FS, "FINISH_BLOCK_BYTES", 8 * 3 * depth * rows)
+@pytest.mark.parametrize("depth,kblocks", [(1, 1), (9, 5), (12, 64)])
+def test_fused_leaf_addrs_in_blocks(monkeypatch, depth, kblocks):
+    """The device scores ``kblocks`` 8-instance kernel blocks a step: one
+    block a step, a ragged last step (blocks of 40 over 48 padded
+    instances), and one step for the whole batch, over a lone level and
+    addresses wider than a byte.  Addresses are exact and predictions
+    equal the machine backend's bit for bit."""
+    monkeypatch.setattr(FS, "SCORE_BLOCK_BYTES", 4 * 3 * 8 * kblocks)
     forest = G.ObliviousForest.random(num_trees=3, depth=depth,
                                       num_features=4, n_bits=8, seed=depth)
-    X = np.random.default_rng(depth).integers(0, 256, (13, 4),
+    X = np.random.default_rng(depth).integers(0, 256, (45, 4),
                                               dtype=np.int64)
-    addrs = FusedGbdtExec(forest, num_chunks=1).leaf_addrs(X)
+    s = session(backend="fused")
+    h = s.load_forest(forest, name="f", num_chunks=1)
+    fused = s.predict(h, X).result
+    fx = s._fused["f"]
+    assert fx.scoring_launch["gbdt"] == (48, min(8 * kblocks, 48), 3,
+                                         1 << depth)
+    addrs = fx.leaf_addrs(X)
     assert addrs.dtype == np.int32
     np.testing.assert_array_equal(addrs, G.reference_leaf_addrs(forest, X))
+    assert fused.dtype == np.float32
+    np.testing.assert_array_equal(
+        fused, s.predict(h, X, backend="machine").result)
+
+
+@pytest.mark.parametrize("t", [1, 7, 8, 9, 127, 128, 129, 1000, 6000])
+def test_device_pairwise_sum_matches_numpy(t):
+    """``pairwise_sum_f32`` is NumPy's float32 row sum, the machine
+    path's ``assemble_leaves``, bit for bit: below 8 terms, at and
+    around the 8-accumulator and 128-term edges, and over the splits of
+    a 1000- and a 6000-tree forest."""
+    import jax
+
+    rng = np.random.default_rng(t)
+    leaves = rng.standard_normal((t, 64)).astype(np.float32)
+    addrs = rng.integers(0, 64, (37, t)).astype(np.int32)
+    values = leaves[np.arange(t)[:, None], addrs.T]            # [T, B]
+    got = np.asarray(jax.jit(fused_query.pairwise_sum_f32)(values))[0]
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, np.ascontiguousarray(values.T).sum(-1))
+    np.testing.assert_array_equal(got, G.assemble_leaves(leaves, addrs))
+    # whatever the addresses' memory layout
+    np.testing.assert_array_equal(
+        got, G.assemble_leaves(leaves, np.asfortranarray(addrs)))
 
 
 def test_fused_table_exec_empty_selection_and_always_true():
@@ -318,6 +350,32 @@ def test_leafbits_launch_counts_what_the_roofline_reads():
     idx = batch * features * 2 * cfg["num_chunks"]
     need = (rows * words + features * words + idx + batch * words) * 4
     assert need == kernels.leafbits_bytes(cfg, batch)
+
+
+def test_scoring_launch_counts_blocks(monkeypatch):
+    """The scoring counter holds the padded batch a device scores, the
+    block it was traced with, the trees and the leaves a tree; each
+    leaf-bits launch covers one block.  A new batch shape retraces."""
+    cfg = harness.load_module(ROOT / "tests" / "bench" / "tiny.py").FOREST
+    s, h, forest = fused_forest(cfg["trees"], cfg["depth"], cfg["features"],
+                                cfg["n_bits"], seed=5)
+    rng = np.random.default_rng(6)
+    s.predict(h, rng.integers(0, 256, (13, cfg["features"]), dtype=np.int64))
+    fx = s._fused["f"]
+    assert fx.scoring_launch == {"gbdt": (16, 16, cfg["trees"], 64)}
+    assert fx.leafbits_launch["gbdt"][0] == 16
+    monkeypatch.setattr(FS, "SCORE_BLOCK_BYTES", 4 * cfg["trees"] * 24)
+    X = rng.integers(0, 256, (70, cfg["features"]), dtype=np.int64)
+    np.testing.assert_array_equal(
+        s.predict(h, X).result, s.predict(h, X, backend="machine").result)
+    assert fx.trace_counts == {"gbdt": 2}
+    assert fx.scoring_launch == {"gbdt": (72, 24, cfg["trees"], 64)}
+    assert fx.leafbits_launch["gbdt"][0] == 24
+    # the benchmark's forest: 512-instance blocks, a batch of 64 in one
+    monkeypatch.undo()
+    assert FS.score_block(1000, 4096) == 512
+    assert FS.score_block(1000, 64) == 64
+    assert FS.score_block(100_000, 64) == 8
 
 
 def test_drop_and_evict_invalidate_fused_cache():

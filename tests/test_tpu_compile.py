@@ -8,7 +8,8 @@ overflow, none of which the CPU interpreter checks.  Nothing runs.
 Widths are ``chip_smoke.py``'s: a 16,777,216-record table of eight
 16-bit columns in 8 record shards at 4 chunks (the predicate kernel's
 row slabs: ``[8, 1024 * 512, 128]``), and a 1000-tree depth-6 forest
-over 8 features of 8 bits scoring 4096 instances.
+over 8 features of 8 bits scoring 4096 instances, its leaves summed
+512 instances at a time.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
@@ -27,6 +28,7 @@ SHARDS, TABLE_ROWS, TABLE_WORDS, CHUNKS = 8, 1024, 65536, 4
 #: the stacked table LUT as ``fused_query.row_slabs`` lays it out
 TABLE_SLABS = (SHARDS, TABLE_ROWS * TABLE_WORDS // 128, 128)
 FOREST_ROWS, FOREST_WORDS, FEATURES, BATCH = 264, 256, 8, 4096
+TREES, SCORE_BLOCK = 1000, 512
 
 
 @pytest.fixture(scope="module")
@@ -103,6 +105,17 @@ def test_gbdt_leafbits_banked_compiles(mosaic):
         mosaic((BATCH, FEATURES * 2), jnp.int32))
     assert "tpu_custom_call" in text
     assert "%clutch_leafbits" in text      # the kernel's name in a trace
+
+
+def test_gbdt_leaf_sum_compiles(mosaic):
+    # one scoring block: 512 instances' addresses into 1000 trees of 64
+    # leaves, each tree's leaves padded to one 128-lane row
+    text = _compile_text(
+        fused_query.gbdt_leaf_sum,
+        mosaic((TREES, SCORE_BLOCK), jnp.int32),
+        mosaic((TREES, 128), jnp.float32))
+    assert "tpu_custom_call" in text
+    assert "%clutch_leafsum" in text      # the kernel's name in a trace
 
 
 def test_encode_lut_compiles(mosaic):
